@@ -57,8 +57,9 @@ echo "=== speculative-decoding smoke (4 virtual devices) ==="
 python scripts/smoke_serving.py spec
 
 echo "=== quantized-KV smoke (interpret kernels + int8-pool serving) ==="
-# the exactness gate for fused dequant (bitwise vs the unquantized
-# kernels on materialized-dequant pages) plus int8 page pools end to end
+# the exactness gate for fused dequant (vs the unquantized kernels on
+# materialized-dequant pages: int8 bitwise, fp8 within 4 f32 ulps) plus
+# int8 page pools end to end
 python scripts/smoke_serving.py quant
 
 echo "=== tiered cluster-prefix smoke (2 replicas, 4 virtual devices) ==="

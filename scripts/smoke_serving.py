@@ -21,8 +21,9 @@ Suites:
            identical to plain greedy decode on the same 4-device pipeline
            with strictly fewer target decode steps
   quant    quantized KV pages: int8/fp8 fused-dequant paged kernels in
-           interpret mode (bitwise vs the unquantized kernels on
-           materialized-dequant pages, tolerance vs the pure-JAX quant
+           interpret mode (vs the unquantized kernels on materialized-
+           dequant pages: int8 bitwise, fp8 within 4 f32 ulps of the
+           output scale; tolerance vs the pure-JAX quant
            oracles), then int8-pool serving on the 4-device pipeline
            (greedy tokens vs fp32, resident-byte savings reported)
   obs      HexTrace observability: a traced + metered serve reproduces the
@@ -342,17 +343,25 @@ def suite_quant() -> None:
             out_v = ops.paged_verify_attention(
                 qv, kq, vq, bt, kv_start=v_start, kv_len=v_len,
                 k_scale=ks, v_scale=vs)
-        # fused dequant must not change a single bit vs the unquantized
-        # kernels on materialized-dequant pages...
-        assert np.array_equal(np.asarray(out), np.asarray(
-            paged_decode_attention_pallas(q, kd, vd, bt, kv_len=kv_len,
-                                          interpret=True))), kv_dtype
-        assert np.array_equal(np.asarray(out_c), np.asarray(
-            paged_context_attention_pallas(qc, kd, vd, bt, q_start=q_start,
-                                           kv_len=ctx_len, interpret=True)))
-        assert np.array_equal(np.asarray(out_v), np.asarray(
-            paged_verify_attention_pallas(qv, kd, vd, bt, kv_start=v_start,
-                                          kv_len=v_len, interpret=True)))
+        # fused dequant vs the unquantized kernels on materialized-dequant
+        # pages: int8 bit for bit; fp8 within a few f32 ulps of the output
+        # scale (XLA's CPU backend fuses the fp8 convert into the score
+        # dot, which then sums in another order)...
+        for fused, mat in (
+                (out, paged_decode_attention_pallas(
+                    q, kd, vd, bt, kv_len=kv_len, interpret=True)),
+                (out_c, paged_context_attention_pallas(
+                    qc, kd, vd, bt, q_start=q_start, kv_len=ctx_len,
+                    interpret=True)),
+                (out_v, paged_verify_attention_pallas(
+                    qv, kd, vd, bt, kv_start=v_start, kv_len=v_len,
+                    interpret=True))):
+            fused, mat = np.asarray(fused), np.asarray(mat)
+            if kv_dtype == "int8":
+                assert np.array_equal(fused, mat), kv_dtype
+            else:
+                ulp = np.finfo(np.float32).eps * np.abs(mat).max()
+                assert np.abs(fused - mat).max() <= 4 * ulp, kv_dtype
         # ...and sits at the kernel tolerance against the pure-JAX oracles
         np.testing.assert_allclose(np.asarray(out), np.asarray(
             ref.paged_decode_attention_quant_ref(q, kq, vq, ks, vs, bt,
@@ -365,8 +374,9 @@ def suite_quant() -> None:
             ref.paged_verify_attention_quant_ref(
                 qv, kq, vq, ks, vs, bt, kv_start=v_start, kv_len=v_len)),
             atol=2e-5)
-    _ok("quantized paged kernels: fused dequant bitwise == materialized, "
-        "oracles within 2e-5 (int8 + fp8, interpret mode)")
+    _ok("quantized paged kernels: fused dequant == materialized (int8 "
+        "bitwise, fp8 within 4 f32 ulps), oracles within 2e-5 "
+        "(interpret mode)")
 
     # int8 page pools end to end on the multi-device pipeline
     from repro.serving.request import synth_workload

@@ -149,6 +149,16 @@ def case_study_cluster() -> Cluster:
     ])
 
 
+def tpu_v5e_one() -> Cluster:
+    """One TPU v5e chip."""
+    return _build([("TPUv5e", 1, "zone-a")])
+
+
+def tpu_v5e_2x2() -> Cluster:
+    """One TPU v5e host: four chips in a 2x2 mesh joined by ICI links."""
+    return _build([("TPUv5e", 4, "zone-a")])
+
+
 def tpu_mixed_slices() -> Cluster:
     """Beyond-paper: two v5e slices of different sizes joined over DCN."""
     return _build([("TPUv5e", 8, "zone-a"), ("TPUv5e", 4, "zone-a"),

@@ -1,16 +1,18 @@
 """Two-phase HexGen scheduler: public entry point (Contribution 2)."""
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Union
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core import cost_model as cm
 from repro.core import genetic
 from repro.core.cluster import Cluster
 from repro.core.genetic import SearchResult
 
 
-def schedule(cluster: Cluster, arch: str, task: cm.Task, *,
+def schedule(cluster: Cluster, arch: Union[str, ModelConfig],
+             task: cm.Task, *,
              deadline: float, rate: float, iters: int = 60,
              seed: int = 0, mutation: str = "hexgen",
              paper_exact: bool = False,
@@ -28,7 +30,9 @@ def schedule(cluster: Cluster, arch: str, task: cm.Task, *,
              host_swap_gbps: float = 0.0,
              prefix_working_set: int = 0,
              cluster_prefix: bool = False) -> SearchResult:
-    """Find an assignment of `cluster` serving `arch` replicas.
+    """Find an assignment of `cluster` serving `arch` replicas. `arch` is
+    a registered name or the ModelConfig being served, so a depth-cut
+    configuration is planned as it will run.
 
     deadline: SLO latency bound (s); rate: request rate (req/s).
     mutation="random" reproduces the paper's strawman baseline.
@@ -75,7 +79,7 @@ def schedule(cluster: Cluster, arch: str, task: cm.Task, *,
     peer-resident blocks behind the shared directory toward each
     replica's reach, matching serving cluster_prefix=True.
     """
-    cfg = get_config(arch)
+    cfg = get_config(arch) if isinstance(arch, str) else arch
     profile = cm.ModelProfile.from_config(cfg, paper_exact=paper_exact,
                                           bytes_per_el=task.bytes_per_el)
     res = genetic.search(cluster, profile, task, deadline=deadline,

@@ -22,8 +22,14 @@ _BACKEND = "xla"          # "xla" | "pallas" | "pallas_interpret"
 
 
 def set_backend(name: str) -> None:
+    """Select the kernel backend. Interpret mode exists to check kernels
+    on the CPU; on a TPU it would silently run the kernels in the slow
+    interpreter instead of on the chip, so it is refused there."""
     global _BACKEND
     assert name in ("xla", "pallas", "pallas_interpret"), name
+    if name == "pallas_interpret" and jax.default_backend() == "tpu":
+        raise ValueError("the pallas_interpret backend is for CPU checks; "
+                         "the default backend is tpu")
     _BACKEND = name
 
 

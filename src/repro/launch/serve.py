@@ -6,18 +6,26 @@ pipeline engine, and serve a Poisson workload end to end.
 
 Every flag is a ``serving.config.ServingConfig`` field — the CLI schema,
 feature gating and derived planning inputs all live there; this driver is
-just the parse -> schedule -> build -> serve spine. The scheduler plans
-for the FULL model on the chosen GPU pool (the paper's setting);
-execution on this CPU container runs the --reduced variant of the same
-architecture through the scheduled stage layout, preserving every
-structural property (stage count, TP degrees, layer ratios).
+just the parse -> schedule -> build -> serve spine.
+
+Without ``--reduced`` the scheduler plans the model that is served, and on
+an accelerator the pool may name no more devices than are present (e.g.
+``--cluster v5e_1`` on one TPU v5e chip). ``--reduced`` is the CPU
+demonstration of the paper's setting: the scheduler plans the FULL model
+on the chosen pool, and the reduced variant runs through that plan's stage
+layout, projected onto its layer count (stage count, TP degrees and layer
+ratios kept; device ids fold onto the host's devices). ``chip_smoke.py`` at
+the repository root is the end-to-end run on the chip.
 """
 from __future__ import annotations
+
+import jax
 
 from repro.configs import get_config
 from repro.core.plan import Assignment, PipelinePlan, StagePlan
 from repro.core.scheduler import schedule
-from repro.serving.config import CLUSTERS, ServingConfig
+from repro.launch.compile_cache import configure_compile_cache
+from repro.serving.config import ServingConfig
 
 
 def scale_assignment(asg: Assignment, full_layers: int,
@@ -47,13 +55,25 @@ def scale_assignment(asg: Assignment, full_layers: int,
     return Assignment(out)
 
 
+def check_pool_fits(pool_size: int, devices) -> None:
+    """On an accelerator a plan runs on the devices it names, so the pool
+    may not be larger than the devices present. (On the CPU, plans for
+    larger pools fold onto the host's devices.)"""
+    if devices[0].platform != "cpu" and pool_size > len(devices):
+        raise SystemExit(
+            f"the pool has {pool_size} devices but only {len(devices)} "
+            f"{devices[0].platform} devices are present")
+
+
 def main() -> None:
     sv = ServingConfig.parse().normalized()
     pool = sv.pool()
+    check_pool_fits(len(pool), jax.devices())
+    configure_compile_cache()
     cfg_full = get_config(sv.arch)
     print(f"scheduling {sv.arch} on {sv.cluster} "
-          f"({len(pool)} GPUs, ${pool.price_per_hour:.2f}/h)...")
-    res = schedule(pool, sv.arch, sv.task(), **sv.schedule_kwargs())
+          f"({len(pool)} devices, ${pool.price_per_hour:.2f}/h)...")
+    res = schedule(pool, cfg_full, sv.task(), **sv.schedule_kwargs())
     plan = res.plan
     print(f"  assignment: {plan.assignment.describe()}")
     print(f"  estimated SLO attainment: {res.attainment*100:.1f}%")

@@ -13,6 +13,7 @@ scan as (xs -> ys).
 from __future__ import annotations
 
 import math
+import zlib
 from functools import partial
 
 import jax
@@ -49,8 +50,9 @@ def sub_kinds(cfg: ModelConfig):
 # Initialization
 # ---------------------------------------------------------------------------
 
-def _norm_params(cfg, P, d=None):
+def _norm_params(cfg, periods, d=None):
     d = d or cfg.d_model
+    P = periods.shape[0]
     w = jnp.ones((P, d), _pdt(cfg))
     if cfg.is_encoder_decoder:                      # LayerNorm with bias
         return {"w": w, "b": jnp.zeros((P, d), _pdt(cfg))}
@@ -61,20 +63,37 @@ def _pdt(cfg):
     return jnp.dtype(cfg.dtype)
 
 
-def _rand(key, name, shape, cfg, scale=0.02):
-    k = jax.random.fold_in(key, hash(name) % (2 ** 31))
-    return (jax.random.normal(k, shape, jnp.float32) * scale).astype(_pdt(cfg))
+def _name_key(key, name):
+    # crc32, not hash(): str hashes are salted per process, and the weights
+    # must come out the same in every process that uses the same seed
+    return jax.random.fold_in(key, zlib.crc32(name.encode()) % (2 ** 31))
 
 
-def _init_attn(key, cfg, P, cross=False):
+def _draw(key, shape, periods, sample):
+    """``sample(k, shape)`` once per period, each from its own key, stacked
+    over a leading period axis. Period p's values do not depend on which
+    other periods are drawn, so one layer can be built alone
+    (``init_layer_params``) with exactly the values of the whole stack."""
+    return jax.vmap(lambda p: sample(jax.random.fold_in(key, p), shape))(
+        periods)
+
+
+def _rand(key, name, periods, shape, cfg, scale=0.02):
+    w = _draw(_name_key(key, name), shape, periods,
+              lambda k, s: jax.random.normal(k, s, jnp.float32))
+    return (w * scale).astype(_pdt(cfg))
+
+
+def _init_attn(key, cfg, periods, cross=False):
     d, hd = cfg.d_model, cfg.head_dim_
     hq, hkv = cfg.num_heads, cfg.num_kv_heads
+    P = periods.shape[0]
     out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
     p = {
-        "wq": _rand(key, "wq", (P, d, hq * hd), cfg),
-        "wk": _rand(key, "wk", (P, d, hkv * hd), cfg),
-        "wv": _rand(key, "wv", (P, d, hkv * hd), cfg),
-        "wo": _rand(key, "wo", (P, hq * hd, d), cfg, out_scale),
+        "wq": _rand(key, "wq", periods, (d, hq * hd), cfg),
+        "wk": _rand(key, "wk", periods, (d, hkv * hd), cfg),
+        "wv": _rand(key, "wv", periods, (d, hkv * hd), cfg),
+        "wo": _rand(key, "wo", periods, (hq * hd, d), cfg, out_scale),
     }
     if cfg.attn_bias and not cross:
         p["bq"] = jnp.zeros((P, hq * hd), _pdt(cfg))
@@ -83,82 +102,85 @@ def _init_attn(key, cfg, P, cross=False):
     return p
 
 
-def _init_mlp(key, cfg, P):
+def _init_mlp(key, cfg, periods):
     d, f = cfg.d_model, cfg.d_ff
     out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
     if cfg.activation == "silu":
-        return {"w_gate": _rand(key, "w_gate", (P, d, f), cfg),
-                "w_up": _rand(key, "w_up", (P, d, f), cfg),
-                "w_down": _rand(key, "w_down", (P, f, d), cfg, out_scale)}
-    return {"w_up": _rand(key, "w_up", (P, d, f), cfg),
-            "w_down": _rand(key, "w_down", (P, f, d), cfg, out_scale)}
+        return {"w_gate": _rand(key, "w_gate", periods, (d, f), cfg),
+                "w_up": _rand(key, "w_up", periods, (d, f), cfg),
+                "w_down": _rand(key, "w_down", periods, (f, d), cfg,
+                                out_scale)}
+    return {"w_up": _rand(key, "w_up", periods, (d, f), cfg),
+            "w_down": _rand(key, "w_down", periods, (f, d), cfg, out_scale)}
 
 
-def _init_moe(key, cfg, P):
+def _init_moe(key, cfg, periods):
     d, f, E = cfg.d_model, cfg.d_ff, cfg.num_experts
     out_scale = 0.02 / math.sqrt(2 * cfg.num_layers)
-    p = {"router": _rand(key, "router", (P, d, E), cfg)}
+    p = {"router": _rand(key, "router", periods, (d, E), cfg)}
     if cfg.activation == "silu":
-        p["w_gate"] = _rand(key, "moe_gate", (P, E, d, f), cfg)
-        p["w_up"] = _rand(key, "moe_up", (P, E, d, f), cfg)
+        p["w_gate"] = _rand(key, "moe_gate", periods, (E, d, f), cfg)
+        p["w_up"] = _rand(key, "moe_up", periods, (E, d, f), cfg)
     else:
-        p["w_up"] = _rand(key, "moe_up", (P, E, d, f), cfg)
-    p["w_down"] = _rand(key, "moe_down", (P, E, f, d), cfg, out_scale)
+        p["w_up"] = _rand(key, "moe_up", periods, (E, d, f), cfg)
+    p["w_down"] = _rand(key, "moe_down", periods, (E, f, d), cfg, out_scale)
     return p
 
 
-def _init_mamba(key, cfg, P):
+def _init_mamba(key, cfg, periods):
     d = cfg.d_model
     din = mamba.d_inner(cfg)
     dtr = mamba._dt_rank(cfg)
     ds = cfg.ssm_d_state
     w = cfg.ssm_d_conv
+    P = periods.shape[0]
     A = jnp.tile(jnp.arange(1, ds + 1, dtype=jnp.float32)[None, None],
                  (P, din, 1))
-    dt_init = jnp.exp(jax.random.uniform(
-        jax.random.fold_in(key, 7), (P, din)) *
-        (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    dt_init = jnp.exp(_draw(jax.random.fold_in(key, 7), (din,), periods,
+                            jax.random.uniform) *
+                      (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
     dt_bias = dt_init + jnp.log(-jnp.expm1(-dt_init))   # inv softplus
     return {
-        "in_proj": _rand(key, "in_proj", (P, d, 2 * din), cfg),
-        "conv_w": _rand(key, "conv_w", (P, din, w), cfg, 0.1),
+        "in_proj": _rand(key, "in_proj", periods, (d, 2 * din), cfg),
+        "conv_w": _rand(key, "conv_w", periods, (din, w), cfg, 0.1),
         "conv_b": jnp.zeros((P, din), _pdt(cfg)),
-        "x_proj": _rand(key, "x_proj", (P, din, dtr + 2 * ds), cfg),
-        "dt_proj": _rand(key, "dt_proj", (P, dtr, din), cfg, 0.1),
+        "x_proj": _rand(key, "x_proj", periods, (din, dtr + 2 * ds), cfg),
+        "dt_proj": _rand(key, "dt_proj", periods, (dtr, din), cfg, 0.1),
         "dt_bias": dt_bias.astype(jnp.float32),
         "A_log": jnp.log(A),
         "D": jnp.ones((P, din), jnp.float32),
-        "out_proj": _rand(key, "mam_out", (P, din, d), cfg,
+        "out_proj": _rand(key, "mam_out", periods, (din, d), cfg,
                           0.02 / math.sqrt(2 * cfg.num_layers)),
     }
 
 
-def _init_mlstm(key, cfg, P):
+def _init_mlstm(key, cfg, periods):
     d = cfg.d_model
     din = xlstm.m_d_inner(cfg)
     qk = xlstm.m_qk_dim(cfg)
     h = cfg.num_heads
     return {
-        "w_up": _rand(key, "w_up", (P, d, 2 * din), cfg),
-        "wq": _rand(key, "m_wq", (P, din, qk), cfg),
-        "wk": _rand(key, "m_wk", (P, din, qk), cfg),
-        "wv": _rand(key, "m_wv", (P, din, din), cfg),
-        "w_i": _rand(key, "m_wi", (P, din, h), cfg),
-        "w_f": _rand(key, "m_wf", (P, din, h), cfg),
-        "out_proj": _rand(key, "m_out", (P, din, d), cfg,
+        "w_up": _rand(key, "w_up", periods, (d, 2 * din), cfg),
+        "wq": _rand(key, "m_wq", periods, (din, qk), cfg),
+        "wk": _rand(key, "m_wk", periods, (din, qk), cfg),
+        "wv": _rand(key, "m_wv", periods, (din, din), cfg),
+        "w_i": _rand(key, "m_wi", periods, (din, h), cfg),
+        "w_f": _rand(key, "m_wf", periods, (din, h), cfg),
+        "out_proj": _rand(key, "m_out", periods, (din, d), cfg,
                           0.02 / math.sqrt(2 * cfg.num_layers)),
     }
 
 
-def _init_slstm(key, cfg, P):
+def _init_slstm(key, cfg, periods):
     d = cfg.d_model
     heads = cfg.num_heads
     dh = d // heads
-    p = {"out_proj": _rand(key, "s_out", (P, d, d), cfg,
+    P = periods.shape[0]
+    p = {"out_proj": _rand(key, "s_out", periods, (d, d), cfg,
                            0.02 / math.sqrt(2 * cfg.num_layers))}
     for g in ("z", "i", "f", "o"):
-        p[f"w_{g}"] = _rand(key, f"s_w{g}", (P, d, d), cfg)
-        p[f"r_{g}"] = _rand(key, f"s_r{g}", (P, heads, dh, dh), cfg)
+        p[f"w_{g}"] = _rand(key, f"s_w{g}", periods, (d, d), cfg)
+        p[f"r_{g}"] = _rand(key, f"s_r{g}", periods, (heads, dh, dh), cfg)
         b = jnp.zeros((P, d), _pdt(cfg))
         if g == "f":
             b = b + 1.0  # forget-gate bias toward remembering
@@ -166,55 +188,56 @@ def _init_slstm(key, cfg, P):
     return p
 
 
-def _init_sub(key, cfg, j, kind, is_moe, P):
+def _init_sub(key, cfg, j, kind, is_moe, periods):
     key = jax.random.fold_in(key, j)
-    sub = {"ln1": _norm_params(cfg, P)}
+    sub = {"ln1": _norm_params(cfg, periods)}
     if kind == ATTN:
-        sub["mixer"] = _init_attn(key, cfg, P)
+        sub["mixer"] = _init_attn(key, cfg, periods)
     elif kind == MAMBA:
-        sub["mixer"] = _init_mamba(key, cfg, P)
+        sub["mixer"] = _init_mamba(key, cfg, periods)
     elif kind == MLSTM:
-        sub["mixer"] = _init_mlstm(key, cfg, P)
+        sub["mixer"] = _init_mlstm(key, cfg, periods)
     elif kind == SLSTM:
-        sub["mixer"] = _init_slstm(key, cfg, P)
+        sub["mixer"] = _init_slstm(key, cfg, periods)
     if cfg.is_encoder_decoder:
-        sub["lnx"] = _norm_params(cfg, P)
-        sub["xattn"] = _init_attn(jax.random.fold_in(key, 91), cfg, P,
+        sub["lnx"] = _norm_params(cfg, periods)
+        sub["xattn"] = _init_attn(jax.random.fold_in(key, 91), cfg, periods,
                                   cross=True)
     has_mlp = cfg.d_ff > 0 and kind in (ATTN, MAMBA)
     if has_mlp:
-        sub["ln2"] = _norm_params(cfg, P)
+        sub["ln2"] = _norm_params(cfg, periods)
         if is_moe:
-            sub["moe"] = _init_moe(jax.random.fold_in(key, 17), cfg, P)
+            sub["moe"] = _init_moe(jax.random.fold_in(key, 17), cfg, periods)
         else:
-            sub["mlp"] = _init_mlp(jax.random.fold_in(key, 19), cfg, P)
+            sub["mlp"] = _init_mlp(jax.random.fold_in(key, 19), cfg, periods)
     return sub
 
 
-def init_params(cfg: ModelConfig, key):
-    P = n_periods(cfg)
+def init_head_params(cfg: ModelConfig, key):
+    """Everything outside the layer stack: embedding, final norm, output
+    head, and the encoder of an encoder-decoder. Equal to those entries of
+    ``init_params(cfg, key)``."""
+    def rand(name, shape):
+        w = jax.random.normal(_name_key(key, name), shape, jnp.float32)
+        return (w * 0.02).astype(_pdt(cfg))
+
     params = {
-        "embed": _rand(key, "embed", (cfg.vocab_size, cfg.d_model), cfg),
+        "embed": rand("embed", (cfg.vocab_size, cfg.d_model)),
         "final_norm": {"w": jnp.ones((cfg.d_model,), _pdt(cfg)),
                        **({"b": jnp.zeros((cfg.d_model,), _pdt(cfg))}
                           if cfg.is_encoder_decoder else {})},
-        "blocks": {
-            f"sub{j}": _init_sub(key, cfg, j, kind, is_moe, P)
-            for j, (kind, is_moe) in enumerate(sub_kinds(cfg))
-        },
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = _rand(key, "lm_head",
-                                  (cfg.d_model, cfg.vocab_size), cfg)
+        params["lm_head"] = rand("lm_head", (cfg.d_model, cfg.vocab_size))
     if cfg.is_encoder_decoder:
-        Pe = cfg.num_encoder_layers
+        pe = jnp.arange(cfg.num_encoder_layers)
         ekey = jax.random.fold_in(key, 1234)
         params["encoder"] = {
             "blocks": {"sub0": {
-                "ln1": _norm_params(cfg, Pe),
-                "mixer": _init_attn(ekey, cfg, Pe),
-                "ln2": _norm_params(cfg, Pe),
-                "mlp": _init_mlp(jax.random.fold_in(ekey, 3), cfg, Pe),
+                "ln1": _norm_params(cfg, pe),
+                "mixer": _init_attn(ekey, cfg, pe),
+                "ln2": _norm_params(cfg, pe),
+                "mlp": _init_mlp(jax.random.fold_in(ekey, 3), cfg, pe),
             }},
             "final_norm": {"w": jnp.ones((cfg.d_model,), _pdt(cfg)),
                            "b": jnp.zeros((cfg.d_model,), _pdt(cfg))},
@@ -222,42 +245,94 @@ def init_params(cfg: ModelConfig, key):
     return params
 
 
+def init_params(cfg: ModelConfig, key):
+    periods = jnp.arange(n_periods(cfg))
+    params = init_head_params(cfg, key)
+    params["blocks"] = {
+        f"sub{j}": _init_sub(key, cfg, j, kind, is_moe, periods)
+        for j, (kind, is_moe) in enumerate(sub_kinds(cfg))
+    }
+    return params
+
+
+def init_layer_params(cfg: ModelConfig, key, i: int):
+    """Un-stacked params of global layer ``i``, equal to
+    ``slice_layer_params(cfg, init_params(cfg, key), i)`` but built without
+    the rest of the stack."""
+    p, j = layer_sub_index(cfg, i)
+    return init_period_layer(cfg, key, p, j=j)
+
+
+def init_period_layer(cfg: ModelConfig, key, p, *, j: int):
+    """``init_layer_params`` of the layer at position ``j`` of period
+    ``p``. ``p`` may be traced, so one compile builds every layer at
+    position ``j``."""
+    kind, is_moe = sub_kinds(cfg)[j]
+    sub = _init_sub(key, cfg, j, kind, is_moe,
+                    jnp.asarray(p, jnp.int32)[None])
+    return jax.tree.map(lambda l: l[0], sub)
+
+
 # ---------------------------------------------------------------------------
 # Cache
 # ---------------------------------------------------------------------------
 
+def _sub_cache(cfg: ModelConfig, kind, lead, batch: int, max_len: int, dt):
+    """One sublayer's contiguous cache with leading dims ``lead``: ``(P,)``
+    for the period-stacked cache, ``()`` for a single layer."""
+    hd = cfg.head_dim_
+    c = {}
+    if kind == ATTN:
+        S = min(max_len, cfg.swa_window) if cfg.swa_window else max_len
+        c["k"] = jnp.zeros((*lead, batch, S, cfg.num_kv_heads, hd), dt)
+        c["v"] = jnp.zeros((*lead, batch, S, cfg.num_kv_heads, hd), dt)
+        if cfg.is_encoder_decoder:
+            c["cross_k"] = jnp.zeros(
+                (*lead, batch, cfg.encoder_seq_len, cfg.num_kv_heads, hd), dt)
+            c["cross_v"] = jnp.zeros(
+                (*lead, batch, cfg.encoder_seq_len, cfg.num_kv_heads, hd), dt)
+    elif kind == MAMBA:
+        din = mamba.d_inner(cfg)
+        c["conv"] = jnp.zeros((*lead, batch, cfg.ssm_d_conv - 1, din), dt)
+        c["h"] = jnp.zeros((*lead, batch, din, cfg.ssm_d_state), jnp.float32)
+    elif kind == MLSTM:
+        h = cfg.num_heads
+        qk_h = xlstm.m_qk_dim(cfg) // h
+        v_h = xlstm.m_d_inner(cfg) // h
+        c["C"] = jnp.zeros((*lead, batch, h, qk_h, v_h), jnp.float32)
+        c["n"] = jnp.zeros((*lead, batch, h, qk_h), jnp.float32)
+    elif kind == SLSTM:
+        for nm in ("c", "n", "m", "h"):
+            c[nm] = jnp.zeros((*lead, batch, cfg.d_model), jnp.float32)
+    return c
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
     """Stacked (n_periods, ...) cache pytree. max_len = prompt + new tokens."""
-    P = n_periods(cfg)
+    lead = (n_periods(cfg),)
     dt = dtype or _pdt(cfg)
-    hd = cfg.head_dim_
-    cache = {}
-    for j, (kind, _) in enumerate(sub_kinds(cfg)):
-        c = {}
-        if kind == ATTN:
-            S = min(max_len, cfg.swa_window) if cfg.swa_window else max_len
-            c["k"] = jnp.zeros((P, batch, S, cfg.num_kv_heads, hd), dt)
-            c["v"] = jnp.zeros((P, batch, S, cfg.num_kv_heads, hd), dt)
-            if cfg.is_encoder_decoder:
-                c["cross_k"] = jnp.zeros(
-                    (P, batch, cfg.encoder_seq_len, cfg.num_kv_heads, hd), dt)
-                c["cross_v"] = jnp.zeros(
-                    (P, batch, cfg.encoder_seq_len, cfg.num_kv_heads, hd), dt)
-        elif kind == MAMBA:
-            din = mamba.d_inner(cfg)
-            c["conv"] = jnp.zeros((P, batch, cfg.ssm_d_conv - 1, din), dt)
-            c["h"] = jnp.zeros((P, batch, din, cfg.ssm_d_state), jnp.float32)
-        elif kind == MLSTM:
-            h = cfg.num_heads
-            qk_h = xlstm.m_qk_dim(cfg) // h
-            v_h = xlstm.m_d_inner(cfg) // h
-            c["C"] = jnp.zeros((P, batch, h, qk_h, v_h), jnp.float32)
-            c["n"] = jnp.zeros((P, batch, h, qk_h), jnp.float32)
-        elif kind == SLSTM:
-            for nm in ("c", "n", "m", "h"):
-                c[nm] = jnp.zeros((P, batch, cfg.d_model), jnp.float32)
-        cache[f"sub{j}"] = c
-    return cache
+    return {f"sub{j}": _sub_cache(cfg, kind, lead, batch, max_len, dt)
+            for j, (kind, _) in enumerate(sub_kinds(cfg))}
+
+
+def _sub_paged_cache(cfg: ModelConfig, kind, lead, n_blocks: int,
+                     block_size: int, n_slots: int, dtype, kv_dtype):
+    """One sublayer's PAGED cache with leading dims ``lead`` (see
+    ``init_paged_cache``): a page pool for attention, per-slot states for
+    recurrent kinds."""
+    if kind != ATTN:
+        return _sub_cache(cfg, kind, lead, n_slots, 1, dtype or _pdt(cfg))
+    if kv_dtype is None:
+        dt = dtype or _pdt(cfg)
+    else:
+        dt = quant.kv_storage_dtype(kv_dtype)
+    shape = (*lead, n_blocks, block_size, cfg.num_kv_heads)
+    c = {"k": jnp.zeros((*shape, cfg.head_dim_), dt),
+         "v": jnp.zeros((*shape, cfg.head_dim_), dt)}
+    if kv_dtype is not None and quant.kv_is_quantized(kv_dtype):
+        c["k_scale"] = jnp.zeros(shape, jnp.float32)
+        c["v_scale"] = jnp.zeros(shape, jnp.float32)
+    return c
 
 
 def init_paged_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
@@ -285,32 +360,10 @@ def init_paged_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
     """
     assert not (cfg.swa_window or cfg.is_encoder_decoder), \
         "paged layout covers full-KV text decoders"
-    P = n_periods(cfg)
-    quantized = kv_dtype is not None and quant.kv_is_quantized(kv_dtype)
-    if kv_dtype is None:
-        dt = dtype or _pdt(cfg)
-    else:
-        dt = quant.kv_storage_dtype(kv_dtype)
-    hd = cfg.head_dim_
-    kinds = sub_kinds(cfg)
-    slot_states = None
-    if any(kind != ATTN for kind, _ in kinds):
-        slot_states = init_cache(cfg, n_slots, 1, dtype)
-    cache = {}
-    for j, (kind, _) in enumerate(kinds):
-        if kind == ATTN:
-            c = {"k": jnp.zeros((P, n_blocks, block_size, cfg.num_kv_heads,
-                                 hd), dt),
-                 "v": jnp.zeros((P, n_blocks, block_size, cfg.num_kv_heads,
-                                 hd), dt)}
-            if quantized:
-                shape = (P, n_blocks, block_size, cfg.num_kv_heads)
-                c["k_scale"] = jnp.zeros(shape, jnp.float32)
-                c["v_scale"] = jnp.zeros(shape, jnp.float32)
-        else:
-            c = slot_states[f"sub{j}"]
-        cache[f"sub{j}"] = c
-    return cache
+    lead = (n_periods(cfg),)
+    return {f"sub{j}": _sub_paged_cache(cfg, kind, lead, n_blocks,
+                                        block_size, n_slots, dtype, kv_dtype)
+            for j, (kind, _) in enumerate(sub_kinds(cfg))}
 
 
 # ---------------------------------------------------------------------------
@@ -588,28 +641,28 @@ def slice_layer_params(cfg: ModelConfig, params, i: int):
 
 def init_layer_cache(cfg: ModelConfig, i: int, batch: int, max_len: int,
                      dtype=None):
-    """Single-layer cache (no period axis)."""
-    p, j = layer_sub_index(cfg, i)
-    full = init_cache(cfg, batch, max_len, dtype)
-    return jax.tree.map(lambda l: l[0], full[f"sub{j}"])
+    """Single-layer cache (no period axis), built at its own shape."""
+    return _sub_cache(cfg, cfg.layer_kind(i), (), batch, max_len,
+                      dtype or _pdt(cfg))
 
 
 def init_layer_paged_cache(cfg: ModelConfig, i: int, n_blocks: int,
                            block_size: int, n_slots: int, dtype=None,
                            kv_dtype=None, kv_guard_layers=()):
-    """Single-layer PAGED cache (no period axis): attention layers get a
-    page pool, recurrent layers their per-slot states.
+    """Single-layer PAGED cache (no period axis), built at its own shape:
+    attention layers get a page pool, recurrent layers their per-slot
+    states.
 
     kv_guard_layers is the quality guard: global layer indices in it keep
     the model-default (unquantized) pool precision whatever ``kv_dtype``
     says — attention sinks concentrate in the first/last layers, so
     pinning those limits the quantization error where it compounds."""
+    assert not (cfg.swa_window or cfg.is_encoder_decoder), \
+        "paged layout covers full-KV text decoders"
     if i in kv_guard_layers:
         kv_dtype = None
-    p, j = layer_sub_index(cfg, i)
-    full = init_paged_cache(cfg, n_blocks, block_size, n_slots, dtype,
-                            kv_dtype=kv_dtype)
-    return jax.tree.map(lambda l: l[0], full[f"sub{j}"])
+    return _sub_paged_cache(cfg, cfg.layer_kind(i), (), n_blocks, block_size,
+                            n_slots, dtype, kv_dtype)
 
 
 # ---------------------------------------------------------------------------

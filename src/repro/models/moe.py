@@ -22,8 +22,6 @@ most once in a token's top-k.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -32,16 +30,6 @@ from repro.models import parallel_ctx as ctx
 from repro.models import quant
 
 MOE_DISPATCH = "grouped"            # "grouped" | "global"
-
-# jax.shard_map landed after the experimental namespace; the replication
-# check flag was also renamed check_rep -> check_vma along the way.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                               # older jax (e.g. 0.4.x)
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SM_CHECK_KW = next(
-    (k for k in ("check_vma", "check_rep")
-     if k in inspect.signature(_shard_map).parameters), None)
 
 
 def moe_mlp(p, x, cfg, *, return_aux=False):
@@ -108,10 +96,9 @@ def _moe_shard_map(p, x, cfg, *, return_aux):
                 jax.lax.psum(aux, model_ax)
         return out, aux
 
-    smkw = {_SM_CHECK_KW: False} if _SM_CHECK_KW else {}
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh, in_specs=(wspec, xspec),
-        out_specs=(xspec, P()), **smkw)(p, x)
+        out_specs=(xspec, P()), check_vma=False)(p, x)
     if return_aux:
         return out, aux
     return out

@@ -40,6 +40,8 @@ CLUSTERS = {
     "full_price": cl.hetero_full_price,
     "homogeneous": cl.homogeneous_a100,
     "tpu_mixed": cl.tpu_mixed_slices,
+    "v5e_1": cl.tpu_v5e_one,
+    "v5e_2x2": cl.tpu_v5e_2x2,
 }
 
 

@@ -26,6 +26,21 @@ from repro.serving.router import Router, ServeStats, default_roles
 from repro.serving.spec import SpecConfig
 
 
+def plan_devices(devices: Sequence, device_ids: Sequence[int]) -> List:
+    """The jax devices a plan stage names. On an accelerator every id must
+    be a device that is present. On the CPU, where tests demonstrate plans
+    for pools larger than the host, ids fold modulo the device count and
+    duplicates collapse (numerically identical; TP only changes layout)."""
+    if devices[0].platform != "cpu":
+        missing = [d for d in device_ids if d >= len(devices)]
+        if missing:
+            raise ValueError(
+                f"the plan names device ids {missing}, but only "
+                f"{len(devices)} {devices[0].platform} devices are present")
+        return [devices[d] for d in device_ids]
+    return list(dict.fromkeys(devices[d % len(devices)] for d in device_ids))
+
+
 class InferenceEngine:
     """``disaggregate=True`` splits the inference phases across replicas:
     arrivals prefill on ``role="prefill"`` replicas and their KV pages
@@ -96,23 +111,19 @@ class InferenceEngine:
                  kvsan: bool = False):
         self.cfg = cfg
         devices = list(devices if devices is not None else jax.devices())
-        if params is None:
-            params = M.init_params(
-                cfg, key if key is not None else jax.random.PRNGKey(0))
+        key = key if key is not None else jax.random.PRNGKey(0)
         if quantize:
             from repro.models.quant import quantize_params
-            params = quantize_params(params, cfg)
+            params = quantize_params(
+                params if params is not None else M.init_params(cfg, key),
+                cfg)
         self.replicas: List[AsymmetricPipeline] = []
         for pipe in assignment.pipelines:
-            stage_devs = []
-            for st in pipe.stages:
-                mapped = [devices[d % len(devices)] for d in st.device_ids]
-                # fewer physical devices than the plan's TP degree: collapse
-                # duplicates (numerically identical; TP only changes layout)
-                uniq = list(dict.fromkeys(mapped))
-                stage_devs.append(uniq)
+            stage_devs = [plan_devices(devices, st.device_ids)
+                          for st in pipe.stages]
+            # params=None: each stage builds its own share from the key
             self.replicas.append(AsymmetricPipeline(
-                cfg, params, pipe.layer_split, stage_devs))
+                cfg, params, pipe.layer_split, stage_devs, key=key))
         if policy != "static" and not slot_mode_supported(cfg):
             warnings.warn(
                 f"{cfg.name}: slot mode needs uniform text decode "

@@ -11,6 +11,8 @@ relay + intra-group broadcast falls out of the resharding copy (DESIGN.md §3).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import types
 from functools import partial
 from typing import List, Optional, Sequence
 
@@ -29,142 +31,251 @@ def _rep(mesh):
     return NamedSharding(mesh, P())
 
 
+def layer_specs(cfg: ModelConfig, i: int, lp, tp: int):
+    """Megatron PartitionSpecs of global layer ``i``'s un-stacked params
+    ``lp`` (arrays or shapes) on a ``tp``-wide ``"model"`` mesh."""
+    j = M.layer_sub_index(cfg, i)[1]
+    stacked = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((1, *x.shape), x.dtype), lp)
+    spec = shardings.param_specs(
+        cfg, {"blocks": {f"sub{j}": stacked}}, tp=tp)["blocks"][f"sub{j}"]
+    # strip the leading period axis of the stacked spec
+    return jax.tree.map(lambda s: P(*s[1:]), spec,
+                        is_leaf=lambda s: isinstance(s, P))
+
+
+_KV_LEAVES = ("k", "v", "k_scale", "v_scale", "cross_k", "cross_v")
+
+
+def cache_shardings(cfg: ModelConfig, mesh: Mesh, shapes):
+    """Shardings of a stage's caches (arrays or shapes) on its mesh. K/V
+    leaves — contiguous rows (b, S, hkv, d) and page pools
+    (n_blocks, bs, hkv[, d]) alike — split their KV-head axis over the
+    ``model`` axis wherever ``wk``/``wv`` split their columns, so each
+    device keeps the heads it computes and a donated step updates them in
+    place. Everything else (recurrent state) is replicated."""
+    tp = mesh.devices.size
+    split = tp > 1 and cfg.num_kv_heads % tp == 0
+
+    def one(path, _):
+        kv = split and getattr(path[-1], "key", None) in _KV_LEAVES
+        return NamedSharding(mesh, P(None, None, "model") if kv else P())
+    return jax.tree_util.tree_map_with_path(one, shapes)
+
+
+def head_names(cfg: ModelConfig, *, is_first: bool, is_last: bool):
+    """The entries of ``init_head_params`` a stage holds: the embedding
+    (and encoder) where tokens enter, the final norm and output head
+    where logits leave."""
+    names = set()
+    if is_first:
+        names.add("embed")
+        if cfg.is_encoder_decoder:
+            names.add("encoder")
+    if is_last:
+        names.add("final_norm")
+        names.add("embed" if cfg.tie_embeddings else "lm_head")
+    return names
+
+
+# ---- stage bodies (pure): `lps` is the stage's per-layer params ----------
+# The params are ARGUMENTS of the jitted programs, never closed over: a
+# closed-over array is lowered as an HLO constant, which at published
+# widths puts gigabytes of weights into the program text.
+
+def _stage_seq(cfg, kinds, lps, x, caches, positions, kv_start, valid,
+               enc_out, lens=None, *, mode):
+    new_caches = []
+    for kind, lp, sc in zip(kinds, lps, caches):
+        x, nc, _ = M.apply_sublayer_seq(
+            cfg, kind, lp, x, sc, positions=positions, kv_start=kv_start,
+            valid=valid, enc_out=enc_out, mode=mode, lens=lens)
+        new_caches.append(nc)
+    return x, new_caches
+
+
+def _stage_decode(cfg, kinds, lps, x, caches, pos, kv_start, enc_out):
+    new_caches = []
+    for kind, lp, sc in zip(kinds, lps, caches):
+        x, nc = M.apply_sublayer_decode(cfg, kind, lp, x, sc, pos=pos,
+                                        kv_start=kv_start)
+        new_caches.append(nc)
+    return x, new_caches
+
+
+def _stage_decode_paged(cfg, kinds, lps, x, caches, pos, block_tables):
+    new_caches = []
+    for kind, lp, sc in zip(kinds, lps, caches):
+        x, nc = M.apply_sublayer_decode_paged(
+            cfg, kind, lp, x, sc, pos=pos, block_tables=block_tables)
+        new_caches.append(nc)
+    return x, new_caches
+
+
+def _stage_context_paged(cfg, kinds, lps, x, caches, positions, q_len,
+                         block_tables):
+    new_caches = []
+    for kind, lp, sc in zip(kinds, lps, caches):
+        x, nc = M.apply_sublayer_context_paged(
+            cfg, kind, lp, x, sc, positions=positions, q_len=q_len,
+            block_tables=block_tables)
+        new_caches.append(nc)
+    return x, new_caches
+
+
+def _stage_verify_paged(cfg, kinds, lps, x, caches, positions, q_len,
+                        block_tables):
+    new_caches = []
+    for kind, lp, sc in zip(kinds, lps, caches):
+        x, nc = M.apply_sublayer_verify_paged(
+            cfg, kind, lp, x, sc, positions=positions, q_len=q_len,
+            block_tables=block_tables)
+        new_caches.append(nc)
+    return x, new_caches
+
+
+def _stage_copy_pages(caches, src, dst):
+    """Duplicate page contents src -> dst in every attention layer's
+    pools (copy-on-write). Donated + jitted so XLA updates the pools
+    in place instead of materializing a copy of each one."""
+    return [M.copy_cache_pages(c, src, dst, stacked=False) for c in caches]
+
+
+def _stage_scatter_pages(caches, dst, payload):
+    """Write migrated-in page payloads (one {"k","v"[,"k_scale",
+    "v_scale"]} pytree per layer of this stage, leading axis = len(dst)
+    blocks) into the pools at block ids `dst` (KV migration landing).
+    Quantized pools ship the payload at wire width plus the float32
+    scale leaves — no requantization on landing."""
+    out = []
+    for c, p in zip(caches, payload):
+        c = dict(c)
+        for n in p:
+            c[n] = c[n].at[dst].set(p[n].astype(c[n].dtype))
+        out.append(c)
+    return out
+
+
+def _stage_scatter_rows_paged(pools, rows, slot_ids, dest):
+    """Scatter the first len(slot_ids) rows of a joint prefill (the rest
+    are compile-shape padding) into the stage's pools: attention K/V into
+    pages at `dest`, recurrent state by slot id. Donated + jitted, so each
+    pool is updated in place rather than copied."""
+    m = slot_ids.shape[0]
+    return [M.scatter_cache_rows_paged(
+        pool, jax.tree.map(lambda r: r[:m], row), slot_ids, dest)
+        for pool, row in zip(pools, rows)]
+
+
+@functools.lru_cache(maxsize=None)
+def stage_programs(cfg: ModelConfig, kinds: tuple):
+    """The jitted programs of a stage whose layers have ``kinds``, shared
+    by every stage of that shape. Each takes the stage's per-layer params
+    first; the caches that follow are donated (updated in place)."""
+    bodies = {
+        "prefill": (partial(_stage_seq, cfg, kinds, mode="prefill"), ()),
+        "decode": (partial(_stage_decode, cfg, kinds), (2,)),
+        "decode_paged": (partial(_stage_decode_paged, cfg, kinds), (2,)),
+        "context_paged": (partial(_stage_context_paged, cfg, kinds), (2,)),
+        "verify_paged": (partial(_stage_verify_paged, cfg, kinds), (2,)),
+        "copy_pages": (_stage_copy_pages, (0,)),
+        "scatter_pages": (_stage_scatter_pages, (0,)),
+        "scatter_rows_paged": (_stage_scatter_rows_paged, (0,)),
+    }
+    # built once per stage shape (memoized above), never per iteration
+    return types.SimpleNamespace(**{
+        name: jax.jit(fn, donate_argnums=d)  # repro: noqa[jit-retrace]
+        for name, (fn, d) in bodies.items()})
+
+
 class StageExecutor:
-    """One pipeline stage: layers [lo, hi) on `devices` with TP=len(devices)."""
+    """One pipeline stage: layers [lo, hi) on `devices` with TP=len(devices).
+
+    With ``params`` (the whole period-stacked pytree) the stage slices and
+    places its layers. With ``params=None`` it builds them from ``key``
+    directly in their sharding on its own devices, so no device ever holds
+    more than this stage's share of the model. The values are
+    ``M.init_params(cfg, key)``'s up to the last bit of the float32 draw:
+    the jitted build may fuse the draw's scale differently."""
 
     def __init__(self, cfg: ModelConfig, params, lo: int, hi: int,
                  devices: Sequence[jax.Device], *, is_first: bool,
-                 is_last: bool):
+                 is_last: bool, key=None):
         self.cfg = cfg
         self.lo, self.hi = lo, hi
         self.is_first, self.is_last = is_first, is_last
         self.tp = len(devices)
         self.mesh = Mesh(np.array(devices), ("model",))
         self.kinds = [cfg.layer_kind(i) for i in range(lo, hi)]
+        assert params is not None or key is not None, \
+            "a stage needs the params or the key to build them from"
 
-        # place per-layer params on this stage's mesh
         self.layer_params = []
+        build = {}                 # period position -> jitted layer init
         for i in range(lo, hi):
-            lp = M.slice_layer_params(cfg, params, i)
-            spec = shardings.param_specs(
-                cfg, {"blocks": {f"sub{M.layer_sub_index(cfg, i)[1]}":
-                                 jax.tree.map(lambda x: x[None], lp)}},
-                tp=self.tp)["blocks"][f"sub{M.layer_sub_index(cfg, i)[1]}"]
-            # strip the leading None of the stacked spec
-            spec = jax.tree.map(
-                lambda s: P(*s[1:]), spec,
-                is_leaf=lambda s: isinstance(s, P))
-            placed = jax.tree.map(
-                lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-                lp, spec)
-            self.layer_params.append(placed)
+            shapes = (M.slice_layer_params(cfg, params, i)
+                      if params is not None else
+                      jax.eval_shape(partial(M.init_layer_params, cfg, i=i),
+                                     key))
+            sh = jax.tree.map(lambda s: NamedSharding(self.mesh, s),
+                              layer_specs(cfg, i, shapes, self.tp))
+            if params is not None:
+                self.layer_params.append(jax.device_put(shapes, sh))
+                continue
+            p, j = M.layer_sub_index(cfg, i)
+            if j not in build:
+                build[j] = jax.jit(partial(M.init_period_layer, cfg, j=j),
+                                   out_shardings=sh)
+            self.layer_params.append(build[j](key, jnp.int32(p)))
 
         self.head_params = None
-        if is_first or is_last:
-            hp = {"embed": params["embed"],
-                  "final_norm": params["final_norm"]}
-            if "lm_head" in params:
-                hp["lm_head"] = params["lm_head"]
-            if cfg.is_encoder_decoder and is_first:
-                hp["encoder"] = params["encoder"]
-            self.head_params = jax.device_put(hp, _rep(self.mesh))
+        names = head_names(cfg, is_first=is_first, is_last=is_last)
+        if names:
+            if params is not None:
+                hp = {n: params[n] for n in names}
+                self.head_params = jax.device_put(hp, _rep(self.mesh))
+            else:
+                self.head_params = jax.jit(
+                    lambda k: {n: v for n, v in
+                               M.init_head_params(cfg, k).items()
+                               if n in names},
+                    out_shardings=_rep(self.mesh))(key)
 
-        self._prefill_jit = jax.jit(
-            partial(self._stage_seq, mode="prefill"),
-            static_argnames=())
-        self._decode_jit = jax.jit(self._stage_decode, donate_argnums=(1,))
-        self._decode_paged_jit = jax.jit(self._stage_decode_paged,
-                                         donate_argnums=(1,))
-        self._context_paged_jit = jax.jit(self._stage_context_paged,
-                                          donate_argnums=(1,))
-        self._verify_paged_jit = jax.jit(self._stage_verify_paged,
-                                         donate_argnums=(1,))
-        self._copy_pages_jit = jax.jit(self._stage_copy_pages,
-                                       donate_argnums=(0,))
-        self._scatter_pages_jit = jax.jit(self._stage_scatter_pages,
-                                          donate_argnums=(0,))
+        progs = stage_programs(cfg, tuple(self.kinds))
+        lps = self.layer_params
+        self._prefill_jit = partial(progs.prefill, lps)
+        self._decode_jit = partial(progs.decode, lps)
+        self._decode_paged_jit = partial(progs.decode_paged, lps)
+        self._context_paged_jit = partial(progs.context_paged, lps)
+        self._verify_paged_jit = partial(progs.verify_paged, lps)
+        self._copy_pages_jit = progs.copy_pages
+        self._scatter_pages_jit = progs.scatter_pages
+        self._scatter_rows_paged_jit = progs.scatter_rows_paged
+        self._allocs = {}
 
     @property
     def has_attn(self) -> bool:
         return ATTN in self.kinds
 
-    # ---- stage bodies (pure) --------------------------------------------
-    def _stage_seq(self, x, caches, positions, kv_start, valid, enc_out,
-                   lens=None, *, mode):
-        new_caches = []
-        for kind, lp, sc in zip(self.kinds, self.layer_params, caches):
-            x, nc, _ = M.apply_sublayer_seq(
-                self.cfg, kind, lp, x, sc, positions=positions,
-                kv_start=kv_start, valid=valid, enc_out=enc_out, mode=mode,
-                lens=lens)
-            new_caches.append(nc)
-        return x, new_caches
-
-    def _stage_decode(self, x, caches, pos, kv_start, enc_out):
-        new_caches = []
-        for kind, lp, sc in zip(self.kinds, self.layer_params, caches):
-            x, nc = M.apply_sublayer_decode(self.cfg, kind, lp, x, sc,
-                                            pos=pos, kv_start=kv_start)
-            new_caches.append(nc)
-        return x, new_caches
-
-    def _stage_decode_paged(self, x, caches, pos, block_tables):
-        new_caches = []
-        for kind, lp, sc in zip(self.kinds, self.layer_params, caches):
-            x, nc = M.apply_sublayer_decode_paged(
-                self.cfg, kind, lp, x, sc, pos=pos,
-                block_tables=block_tables)
-            new_caches.append(nc)
-        return x, new_caches
-
-    def _stage_context_paged(self, x, caches, positions, q_len,
-                             block_tables):
-        new_caches = []
-        for kind, lp, sc in zip(self.kinds, self.layer_params, caches):
-            x, nc = M.apply_sublayer_context_paged(
-                self.cfg, kind, lp, x, sc, positions=positions, q_len=q_len,
-                block_tables=block_tables)
-            new_caches.append(nc)
-        return x, new_caches
-
-    def _stage_verify_paged(self, x, caches, positions, q_len,
-                            block_tables):
-        new_caches = []
-        for kind, lp, sc in zip(self.kinds, self.layer_params, caches):
-            x, nc = M.apply_sublayer_verify_paged(
-                self.cfg, kind, lp, x, sc, positions=positions, q_len=q_len,
-                block_tables=block_tables)
-            new_caches.append(nc)
-        return x, new_caches
-
-    def _stage_copy_pages(self, caches, src, dst):
-        """Duplicate page contents src -> dst in every attention layer's
-        pools (copy-on-write). Donated + jitted so XLA updates the pools
-        in place instead of materializing a copy of each one."""
-        return [M.copy_cache_pages(c, src, dst, stacked=False)
-                for c in caches]
-
-    def _stage_scatter_pages(self, caches, dst, payload):
-        """Write migrated-in page payloads (one {"k","v"[,"k_scale",
-        "v_scale"]} pytree per layer of this stage, leading axis = len(dst)
-        blocks) into the pools at block ids `dst` (KV migration landing).
-        Quantized pools ship the payload at wire width plus the float32
-        scale leaves — no requantization on landing."""
-        out = []
-        for c, p in zip(caches, payload):
-            c = dict(c)
-            for n in p:
-                c[n] = c[n].at[dst].set(p[n].astype(c[n].dtype))
-            out.append(c)
-        return out
-
     # ---- cache ------------------------------------------------------------
+    def _alloc(self, name, make):
+        """``make()`` (fresh caches) built directly on this stage's devices
+        in their ``cache_shardings`` — never staged through the default
+        device. One compile per ``name``, which must encode every shape
+        ``make`` depends on."""
+        fn = self._allocs.get(name)
+        if fn is None:
+            out = cache_shardings(self.cfg, self.mesh, jax.eval_shape(make))
+            # memoized per name: one program per cache shape
+            fn = self._allocs[name] = jax.jit(  # repro: noqa[jit-retrace]
+                make, out_shardings=out)
+        return fn()
+
     def make_caches(self, batch: int, max_len: int):
-        out = []
-        for i in range(self.lo, self.hi):
-            c = M.init_layer_cache(self.cfg, i, batch, max_len)
-            out.append(jax.device_put(c, _rep(self.mesh)))
-        return out
+        return self._alloc(
+            ("cache", batch, max_len),
+            lambda: [M.init_layer_cache(self.cfg, i, batch, max_len)
+                     for i in range(self.lo, self.hi)])
 
     def make_paged_caches(self, n_blocks: int, block_size: int,
                           n_slots: int, *, kv_dtype=None,
@@ -175,13 +286,13 @@ class StageExecutor:
         `kv_dtype` selects the pool storage precision (None = model
         default); layers in `kv_guard_layers` (GLOBAL indices) stay at
         model precision regardless (quality guard)."""
-        out = []
-        for i in range(self.lo, self.hi):
-            c = M.init_layer_paged_cache(self.cfg, i, n_blocks, block_size,
-                                         n_slots, kv_dtype=kv_dtype,
-                                         kv_guard_layers=kv_guard_layers)
-            out.append(jax.device_put(c, _rep(self.mesh)))
-        return out
+        guard = tuple(kv_guard_layers)
+        return self._alloc(
+            ("paged", n_blocks, block_size, n_slots, kv_dtype, guard),
+            lambda: [M.init_layer_paged_cache(
+                self.cfg, i, n_blocks, block_size, n_slots,
+                kv_dtype=kv_dtype, kv_guard_layers=guard)
+                for i in range(self.lo, self.hi)])
 
 
 def slot_mode_supported(cfg) -> bool:
@@ -203,10 +314,11 @@ def context_mode_supported(cfg) -> bool:
 
 
 class AsymmetricPipeline:
-    """A full model replica as a chain of StageExecutors."""
+    """A full model replica as a chain of StageExecutors. ``params=None``
+    builds every stage's share from ``key`` on that stage's devices."""
 
     def __init__(self, cfg: ModelConfig, params, stage_layers: Sequence[int],
-                 stage_devices: Sequence[Sequence[jax.Device]]):
+                 stage_devices: Sequence[Sequence[jax.Device]], *, key=None):
         assert sum(stage_layers) == cfg.num_layers
         self.cfg = cfg
         self.stages: List[StageExecutor] = []
@@ -214,7 +326,8 @@ class AsymmetricPipeline:
         for si, (nl, devs) in enumerate(zip(stage_layers, stage_devices)):
             self.stages.append(StageExecutor(
                 cfg, params, lo, lo + nl, devs,
-                is_first=(si == 0), is_last=(si == len(stage_layers) - 1)))
+                is_first=(si == 0), is_last=(si == len(stage_layers) - 1),
+                key=key))
             lo += nl
         self.caches = None
         self._pos = 0
@@ -454,12 +567,10 @@ class AsymmetricPipeline:
                 scratch = st.make_caches(b, self.slot_len)
                 x, rows = st._prefill_jit(x, scratch, positions, None,
                                           valid, None, lens)
-                dest = jnp.asarray(stage_dest[si], jnp.int32)
-                self.paged_caches[si] = [
-                    M.scatter_cache_rows_paged(
-                        pool, jax.tree.map(lambda r: r[:m], row),
-                        slot_ids, dest)
-                    for pool, row in zip(self.paged_caches[si], rows)]
+                self.paged_caches[si] = st._scatter_rows_paged_jit(
+                    self.paged_caches[si], rows,
+                    jnp.asarray(slot_ids, jnp.int32),
+                    jnp.asarray(stage_dest[si], jnp.int32))
         x_last = x[jnp.arange(m), lens[:m] - 1][:, None]
         return np.asarray(self._head(x_last)[:, 0])
 
