@@ -5,6 +5,9 @@ CPU and the dry-run lowering path — flash-style online softmax / chunked scan
 so 32k-500k sequences never materialize O(s^2) score tensors), and (ii) an
 optional Pallas TPU kernel behind ``set_backend("pallas")`` (validated in
 interpret mode by tests). The oracles live in ref.py.
+
+The attention entry points run under ``jax.named_scope("attention")``, so
+their device operations carry that scope in their HLO metadata.
 """
 from __future__ import annotations
 
@@ -54,6 +57,7 @@ def backend(name: str):
 # Flash attention (prefill / training)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def flash_attention(q, k, v, *, causal=True, window=0, kv_len=None,
                     kv_start=None, q_block=512, kv_block=512, scale=None):
     """Chunked attention. q (b,sq,hq,d); k,v (b,skv,hkv,d); GQA via hq%hkv==0.
@@ -341,6 +345,7 @@ def _swa_blocks(qf, kf, vf, *, window, q_block, kv_len, kv_start, causal,
 # Decode attention (one new token against a cache)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def decode_attention(q, k, v, *, kv_len=None, kv_start=None, kv_block=0,
                      scale=None):
     """q (b,1,hq,d) against cache k,v (b,S,hkv,d). kv_len (b,) valid lengths.
@@ -390,6 +395,7 @@ def _decode_attention_xla(q, k, v, *, kv_len, kv_start, scale):
 # Paged decode attention (one new token against a block-paged cache)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def paged_decode_attention(q, k_pages, v_pages, block_tables, *, kv_len=None,
                            scale=None, k_scale=None, v_scale=None):
     """q (b,1,hq,d) against a paged cache: k_pages/v_pages
@@ -438,6 +444,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, *, kv_len=None,
                                  scale=scale)
 
 
+@jax.named_scope("attention")
 def paged_context_attention(q, k_pages, v_pages, block_tables, *, q_start,
                             kv_len, scale=None, k_scale=None, v_scale=None):
     """CONTEXT PREFILL against a block-paged cache: q (b,C,hq,d) is a chunk
@@ -482,6 +489,7 @@ def paged_context_attention(q, k_pages, v_pages, block_tables, *, q_start,
                                      kv_len=kv_len, scale=scale)
 
 
+@jax.named_scope("attention")
 def paged_verify_attention(q, k_pages, v_pages, block_tables, *, kv_start,
                            kv_len, scale=None, k_scale=None, v_scale=None):
     """MULTI-TOKEN VERIFICATION against a block-paged cache (speculative

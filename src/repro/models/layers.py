@@ -3,6 +3,12 @@
 All functions are pure; parameters are plain dict pytrees. Sequence mixing
 goes through repro.kernels.ops so the Pallas/XLA backend switch applies
 uniformly.
+
+Named scopes (``jax.named_scope``; ``repro.obs.trace.SCOPE_NAMES``) mark
+what each device operation computes in its HLO metadata: ``attn_proj``
+for the q/k/v and output projections, ``attention`` for rope, the paged
+K/V write and the attention kernels, ``mlp`` for the MLP. They change
+metadata only.
 """
 from __future__ import annotations
 
@@ -26,6 +32,7 @@ def layer_norm(x, w, b, eps=1e-5):
     return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w + b
 
 
+@jax.named_scope("attention")
 def rope(x, positions, theta):
     """x: (b, s, h, d); positions: (b, s) or (s,). theta==0 disables."""
     if theta == 0.0:
@@ -55,6 +62,7 @@ def sinusoidal_positions(positions, d):
 # Attention block
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attn_proj")
 def _qkv(p, x, cfg):
     b, s, _ = x.shape
     hd = cfg.head_dim_
@@ -69,6 +77,29 @@ def _qkv(p, x, cfg):
     k = k.reshape(b, s, cfg.num_kv_heads, hd)
     v = v.reshape(b, s, cfg.num_kv_heads, hd)
     return q, k, v
+
+
+@jax.named_scope("attn_proj")
+def _wo(p, o):
+    """Output projection of the attention heads o (b, s, heads * hd)."""
+    return mm(o, p["wo"])
+
+
+@jax.named_scope("attention")
+def _write_pages(cache, blk, off, k, v):
+    """Scatter new K/V rows into the page pools at (page blk, offset off);
+    a QUANTIZED pool (cache carries "k_scale"/"v_scale") quantizes the rows
+    on write (models/quant.quantize_kv_rows). Returns the new cache."""
+    if "k_scale" in cache:
+        kvd = kv_dtype_name(cache["k"].dtype)
+        kq, ks = quantize_kv_rows(k, kvd)
+        vq, vs = quantize_kv_rows(v, kvd)
+        return {"k": cache["k"].at[blk, off].set(kq),
+                "v": cache["v"].at[blk, off].set(vq),
+                "k_scale": cache["k_scale"].at[blk, off].set(ks),
+                "v_scale": cache["v_scale"].at[blk, off].set(vs)}
+    return {"k": cache["k"].at[blk, off].set(k.astype(cache["k"].dtype)),
+            "v": cache["v"].at[blk, off].set(v.astype(cache["v"].dtype))}
 
 
 def attn_prefill(p, x, cfg, *, positions, kv_start=None, cache=None,
@@ -87,30 +118,35 @@ def attn_prefill(p, x, cfg, *, positions, kv_start=None, cache=None,
     o = ops.flash_attention(q, k, v, causal=True, window=window,
                             kv_start=kv_start)
     b, s, _, _ = q.shape
-    out = mm(o.reshape(b, s, -1), p["wo"])
+    out = _wo(p, o.reshape(b, s, -1))
     new_cache = None
     if cache is not None:
-        if window and cache["k"].shape[1] <= window:
-            W = cache["k"].shape[1]
-            if s >= W:
-                # true ring layout: position p lives at slot p % W, so the
-                # decode write at slot pos % W evicts exactly the oldest key
-                ck = jnp.roll(k[:, -W:], s % W, axis=1)
-                cv = jnp.roll(v[:, -W:], s % W, axis=1)
-            else:
-                pad = W - s
-                ck = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                cv = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            new_cache = {"k": ck.astype(cache["k"].dtype),
-                         "v": cv.astype(cache["v"].dtype)}
-        else:
-            S = cache["k"].shape[1]
-            nk = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
-            nv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
-            new_cache = {"k": nk, "v": nv}
+        new_cache = _write_rows(cache, k, v, s, window)
     return out, new_cache
+
+
+@jax.named_scope("attention")
+def _write_rows(cache, k, v, s, window):
+    """A prompt's K/V (b, s, hkv, hd) written into a contiguous cache from
+    position 0, or the last W keys of a ring cache. Returns the cache."""
+    if window and cache["k"].shape[1] <= window:
+        W = cache["k"].shape[1]
+        if s >= W:
+            # true ring layout: position p lives at slot p % W, so the
+            # decode write at slot pos % W evicts exactly the oldest key
+            ck = jnp.roll(k[:, -W:], s % W, axis=1)
+            cv = jnp.roll(v[:, -W:], s % W, axis=1)
+        else:
+            pad = W - s
+            ck = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            cv = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return {"k": ck.astype(cache["k"].dtype),
+                "v": cv.astype(cache["v"].dtype)}
+    nk = jax.lax.dynamic_update_slice_in_dim(
+        cache["k"], k.astype(cache["k"].dtype), 0, axis=1)
+    nv = jax.lax.dynamic_update_slice_in_dim(
+        cache["v"], v.astype(cache["v"].dtype), 0, axis=1)
+    return {"k": nk, "v": nv}
 
 
 def attn_decode(p, x, cfg, *, pos, kv_start=None, cache=None, window=None):
@@ -129,6 +165,17 @@ def attn_decode(p, x, cfg, *, pos, kv_start=None, cache=None, window=None):
     posb = pos[:, None] if per_row else jnp.full((b, 1), pos, jnp.int32)
     q = rope(q, posb, cfg.rope_theta)
     k = rope(k, posb, cfg.rope_theta)
+    o, nk, nv = _decode_core(q, k, v, pos, per_row, cache, window,
+                             kv_start)
+    out = _wo(p, o.reshape(b, 1, -1))
+    return out, {"k": nk, "v": nv}
+
+
+@jax.named_scope("attention")
+def _decode_core(q, k, v, pos, per_row, cache, window, kv_start):
+    """The new token's K/V write into a contiguous (or ring) cache and
+    attention over it. Returns (o, new k cache, new v cache)."""
+    b = q.shape[0]
     ring = window and cache["k"].shape[1] <= window
     ridx = jnp.arange(b)
     if ring:
@@ -157,8 +204,7 @@ def attn_decode(p, x, cfg, *, pos, kv_start=None, cache=None, window=None):
                 cache["v"], v.astype(cache["v"].dtype), pos, axis=1)
         kv_len = jnp.broadcast_to(pos + 1, (b,))
         o = ops.decode_attention(q, nk, nv, kv_len=kv_len, kv_start=kv_start)
-    out = mm(o.reshape(b, 1, -1), p["wo"])
-    return out, {"k": nk, "v": nv}
+    return o, nk, nv
 
 
 def attn_decode_paged(p, x, cfg, *, pos, block_tables, cache):
@@ -184,29 +230,17 @@ def attn_decode_paged(p, x, cfg, *, pos, block_tables, cache):
     posb = pos[:, None]
     q = rope(q, posb, cfg.rope_theta)
     k = rope(k, posb, cfg.rope_theta)
-    bs = cache["k"].shape[1]
-    ridx = jnp.arange(b)
-    blk = jnp.asarray(block_tables, jnp.int32)[ridx, pos // bs]
-    off = pos % bs
-    kv_len = pos + 1
-    if "k_scale" in cache:
-        kvd = kv_dtype_name(cache["k"].dtype)
-        kq, ks = quantize_kv_rows(k[:, 0], kvd)
-        vq, vs = quantize_kv_rows(v[:, 0], kvd)
-        nk = cache["k"].at[blk, off].set(kq)
-        nv = cache["v"].at[blk, off].set(vq)
-        nks = cache["k_scale"].at[blk, off].set(ks)
-        nvs = cache["v_scale"].at[blk, off].set(vs)
-        o = ops.paged_decode_attention(q, nk, nv, block_tables,
-                                       kv_len=kv_len, k_scale=nks,
-                                       v_scale=nvs)
-        out = mm(o.reshape(b, 1, -1), p["wo"])
-        return out, {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
-    nk = cache["k"].at[blk, off].set(k[:, 0].astype(cache["k"].dtype))
-    nv = cache["v"].at[blk, off].set(v[:, 0].astype(cache["v"].dtype))
-    o = ops.paged_decode_attention(q, nk, nv, block_tables, kv_len=kv_len)
-    out = mm(o.reshape(b, 1, -1), p["wo"])
-    return out, {"k": nk, "v": nv}
+    with jax.named_scope("attention"):
+        bs = cache["k"].shape[1]
+        ridx = jnp.arange(b)
+        blk = jnp.asarray(block_tables, jnp.int32)[ridx, pos // bs]
+        off = pos % bs
+        nc = _write_pages(cache, blk, off, k[:, 0], v[:, 0])
+        o = ops.paged_decode_attention(q, nc["k"], nc["v"], block_tables,
+                                       kv_len=pos + 1,
+                                       k_scale=nc.get("k_scale"),
+                                       v_scale=nc.get("v_scale"))
+    return _wo(p, o.reshape(b, 1, -1)), nc
 
 
 def attn_context_paged(p, x, cfg, *, positions, q_len, block_tables, cache):
@@ -229,35 +263,30 @@ def attn_context_paged(p, x, cfg, *, positions, q_len, block_tables, cache):
     positions = jnp.asarray(positions, jnp.int32)       # (b, C) absolute
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        tbl = jnp.asarray(block_tables, jnp.int32)
+        blk, off = _chunk_pages(cache, tbl, positions, q_len)
+        q_start = positions[:, 0]
+        kv_len = q_start + jnp.asarray(q_len, jnp.int32)
+        nc = _write_pages(cache, blk, off, k, v)
+        o = ops.paged_context_attention(q, nc["k"], nc["v"], tbl,
+                                        q_start=q_start, kv_len=kv_len,
+                                        k_scale=nc.get("k_scale"),
+                                        v_scale=nc.get("v_scale"))
+    return _wo(p, o.reshape(b, C, -1)), nc
+
+
+def _chunk_pages(cache, tbl, positions, q_len):
+    """Page and offset of each chunk token (b, C); padding tokens
+    (j >= q_len) go to the reserved null page."""
     bs = cache["k"].shape[1]
-    tbl = jnp.asarray(block_tables, jnp.int32)
+    C = positions.shape[1]
     max_pos = tbl.shape[1] * bs - 1
     valid = jnp.arange(C)[None, :] < jnp.asarray(q_len, jnp.int32)[:, None]
     posc = jnp.minimum(positions, max_pos)              # pad rows stay legal
     blk = jnp.take_along_axis(tbl, posc // bs, axis=1)  # (b, C)
     blk = jnp.where(valid, blk, 0)                      # pads -> null page
-    off = posc % bs
-    q_start = positions[:, 0]
-    kv_len = q_start + jnp.asarray(q_len, jnp.int32)
-    if "k_scale" in cache:
-        kvd = kv_dtype_name(cache["k"].dtype)
-        kq, ks = quantize_kv_rows(k, kvd)
-        vq, vs = quantize_kv_rows(v, kvd)
-        nk = cache["k"].at[blk, off].set(kq)
-        nv = cache["v"].at[blk, off].set(vq)
-        nks = cache["k_scale"].at[blk, off].set(ks)
-        nvs = cache["v_scale"].at[blk, off].set(vs)
-        o = ops.paged_context_attention(q, nk, nv, tbl, q_start=q_start,
-                                        kv_len=kv_len, k_scale=nks,
-                                        v_scale=nvs)
-        out = mm(o.reshape(b, C, -1), p["wo"])
-        return out, {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
-    nk = cache["k"].at[blk, off].set(k.astype(cache["k"].dtype))
-    nv = cache["v"].at[blk, off].set(v.astype(cache["v"].dtype))
-    o = ops.paged_context_attention(q, nk, nv, tbl, q_start=q_start,
-                                    kv_len=kv_len)
-    out = mm(o.reshape(b, C, -1), p["wo"])
-    return out, {"k": nk, "v": nv}
+    return blk, posc % bs
 
 
 def attn_verify_paged(p, x, cfg, *, positions, q_len, block_tables, cache):
@@ -285,35 +314,17 @@ def attn_verify_paged(p, x, cfg, *, positions, q_len, block_tables, cache):
     positions = jnp.asarray(positions, jnp.int32)       # (b, T) absolute
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
-    bs = cache["k"].shape[1]
-    tbl = jnp.asarray(block_tables, jnp.int32)
-    max_pos = tbl.shape[1] * bs - 1
-    valid = jnp.arange(T)[None, :] < jnp.asarray(q_len, jnp.int32)[:, None]
-    posc = jnp.minimum(positions, max_pos)              # pad rows stay legal
-    blk = jnp.take_along_axis(tbl, posc // bs, axis=1)  # (b, T)
-    blk = jnp.where(valid, blk, 0)                      # pads -> null page
-    off = posc % bs
-    kv_start = positions[:, 0]
-    kv_len = kv_start + jnp.asarray(q_len, jnp.int32)
-    if "k_scale" in cache:
-        kvd = kv_dtype_name(cache["k"].dtype)
-        kq, ks = quantize_kv_rows(k, kvd)
-        vq, vs = quantize_kv_rows(v, kvd)
-        nk = cache["k"].at[blk, off].set(kq)
-        nv = cache["v"].at[blk, off].set(vq)
-        nks = cache["k_scale"].at[blk, off].set(ks)
-        nvs = cache["v_scale"].at[blk, off].set(vs)
-        o = ops.paged_verify_attention(q, nk, nv, tbl, kv_start=kv_start,
-                                       kv_len=kv_len, k_scale=nks,
-                                       v_scale=nvs)
-        out = mm(o.reshape(b, T, -1), p["wo"])
-        return out, {"k": nk, "v": nv, "k_scale": nks, "v_scale": nvs}
-    nk = cache["k"].at[blk, off].set(k.astype(cache["k"].dtype))
-    nv = cache["v"].at[blk, off].set(v.astype(cache["v"].dtype))
-    o = ops.paged_verify_attention(q, nk, nv, tbl, kv_start=kv_start,
-                                   kv_len=kv_len)
-    out = mm(o.reshape(b, T, -1), p["wo"])
-    return out, {"k": nk, "v": nv}
+    with jax.named_scope("attention"):
+        tbl = jnp.asarray(block_tables, jnp.int32)
+        blk, off = _chunk_pages(cache, tbl, positions, q_len)
+        kv_start = positions[:, 0]
+        kv_len = kv_start + jnp.asarray(q_len, jnp.int32)
+        nc = _write_pages(cache, blk, off, k, v)
+        o = ops.paged_verify_attention(q, nc["k"], nc["v"], tbl,
+                                       kv_start=kv_start, kv_len=kv_len,
+                                       k_scale=nc.get("k_scale"),
+                                       v_scale=nc.get("v_scale"))
+    return _wo(p, o.reshape(b, T, -1)), nc
 
 
 def cross_attn(p, x, cfg, *, enc_kv=None, enc_out=None):
@@ -329,7 +340,7 @@ def cross_attn(p, x, cfg, *, enc_kv=None, enc_out=None):
         enc_kv = {"k": k, "v": v}
     o = ops.flash_attention(q, enc_kv["k"].astype(q.dtype),
                             enc_kv["v"].astype(q.dtype), causal=False)
-    return mm(o.reshape(b, s, -1), p["wo"]), enc_kv
+    return _wo(p, o.reshape(b, s, -1)), enc_kv
 
 
 def attn_encoder(p, x, cfg):
@@ -337,13 +348,14 @@ def attn_encoder(p, x, cfg):
     q, k, v = _qkv(p, x, cfg)
     o = ops.flash_attention(q, k, v, causal=False)
     b, s = x.shape[:2]
-    return mm(o.reshape(b, s, -1), p["wo"])
+    return _wo(p, o.reshape(b, s, -1))
 
 
 # ---------------------------------------------------------------------------
 # MLP
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("mlp")
 def mlp(p, x, cfg):
     if cfg.activation == "silu":
         return mm(jax.nn.silu(mm(x, p["w_gate"])) * mm(x, p["w_up"]),

@@ -370,6 +370,7 @@ def init_paged_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
 # Stack application
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("norm")
 def _norm(cfg, p, x):
     if cfg.is_encoder_decoder:
         return layers.layer_norm(x, p["w"], p["b"], cfg.norm_eps)
@@ -789,6 +790,7 @@ def scatter_cache_rows_paged(pool, rows, slot_ids, dest_blocks, *,
 # Embedding / head
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def _embed(cfg, params, tokens):
     x = params["embed"][tokens]
     if cfg.family == "vlm":                      # gemma-style scaling
@@ -796,6 +798,7 @@ def _embed(cfg, params, tokens):
     return x
 
 
+@jax.named_scope("head")
 def _head(cfg, params, x):
     x = _norm(cfg, params["final_norm"], x)
     if cfg.tie_embeddings:

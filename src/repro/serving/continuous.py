@@ -38,7 +38,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_STEP, NULL_TRACER
 from repro.serving.block_manager import (BlockPool, BlockTable, HostPagePool,
                                          PrefixIndex, blocks_for_tokens,
                                          chunk_hashes)
@@ -76,9 +76,10 @@ class SlotEngine:
     """Slot bookkeeping + the joint insert/decode iteration, shared by the
     monolithic and pipeline executors. Subclasses provide:
 
-      _prefill_insert(toks (b,P), lens (b,), slot_ids) -> logits (m, V)
+      _prefill_insert(toks (b,P), lens (b,), slot_ids, rids) -> logits (m, V)
           where m = len(slot_ids) <= b; rows beyond m are compile-shape
-          padding to be dropped before the cache scatter
+          padding to be dropped before the cache scatter; rids are the
+          rows' request ids when tracing is on (else empty)
       _decode_all(toks (n_slots,), pos (n_slots,))     -> logits (n_slots, V)
     """
 
@@ -91,6 +92,9 @@ class SlotEngine:
         # HexTrace: the Router (or a test) swaps in a live Tracer; the
         # null default keeps every emission site a single attribute check
         self.tracer = NULL_TRACER
+        # the serve loop's clock (bind_clock): request stamps are read on
+        # it once the call that produced them has returned
+        self.clock = None
         self.replica_id = 0
         self.slots = [_Slot() for _ in range(n_slots)]
         self._queue: Deque[Request] = deque()
@@ -98,6 +102,27 @@ class SlotEngine:
         self.rejected = 0          # oversized requests turned away
         self.preemptions = 0       # paged: slots recomputed after eviction
         self._admit_seq = 0
+
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        # the pipeline's model-step spans ride the engine's tracer
+        self._tracer = tracer
+        pipe = getattr(self, "pipeline", None)
+        if pipe is not None:
+            pipe.tracer = tracer
+
+    def bind_clock(self, clock) -> None:
+        """The serve loop's clock (``run_serve_loop`` binds it)."""
+        self.clock = clock
+
+    def _now(self, now: float) -> float:
+        """The serve loop's clock now; ``now`` (the iteration's start,
+        which a virtual clock holds all iteration) without a loop."""
+        return self.clock.now() if self.clock is not None else now
 
     # ---- slot state ------------------------------------------------------
     def free_slots(self) -> List[int]:
@@ -132,36 +157,48 @@ class SlotEngine:
         free = self.free_slots()
         if self._queue and free:
             batch = []
-            while self._queue and len(batch) < len(free):
-                r = self._queue[0]
-                # a request must fit prompt + all its decode steps on this
-                # engine (slot length, and for the paged engine the whole
-                # block pool); reject it alone (empty output, counted in
-                # ServeStats.rejected) instead of crashing the serve loop
-                # and losing every in-flight request
-                if not self._fits(r):
-                    self._queue.popleft()
-                    self.rejected += 1
-                    warnings.warn(
-                        f"request {r.rid}: prompt {len(r.prompt)} + "
-                        f"max_new {r.max_new_tokens} cannot fit this "
-                        "engine; rejected with empty output")
-                    comps.append((r, np.zeros(0, np.int32), None))
-                    continue
-                # admissible later but not right now (paged: not enough
-                # free blocks yet) — keep it queued, FIFO order intact
-                if not self._can_admit(r, batch):
-                    break
-                self._queue.popleft()
-                batch.append(r)
+            tr = self.tracer
+            admit = (tr.step("admit", pid=self.replica_id) if tr.enabled
+                     else NULL_STEP)
+            with admit:
+                self._admit_batch(batch, len(free), comps)
+                if tr.enabled:
+                    admit.set(rids=[r.rid for r in batch])
             if batch:
-                self._insert_batch(batch, free[:len(batch)])
+                self._insert_batch(batch, free[:len(batch)], now)
         # nothing active (e.g. a rejection-only cycle): no decode to run —
         # and possibly no caches allocated yet to run it on
         done = self._step(now) if self.active else []
         comps.extend((req, np.asarray(out, np.int32), None)
                      for req, out in done)
         return comps, self.virtual_step_cost
+
+    def _admit_batch(self, batch: List[Request], n_free: int,
+                     comps: List) -> None:
+        """Move queued requests into ``batch`` (at most ``n_free``), FIFO;
+        a request that can never fit is rejected into ``comps``."""
+        while self._queue and len(batch) < n_free:
+            r = self._queue[0]
+            # a request must fit prompt + all its decode steps on this
+            # engine (slot length, and for the paged engine the whole
+            # block pool); reject it alone (empty output, counted in
+            # ServeStats.rejected) instead of crashing the serve loop
+            # and losing every in-flight request
+            if not self._fits(r):
+                self._queue.popleft()
+                self.rejected += 1
+                warnings.warn(
+                    f"request {r.rid}: prompt {len(r.prompt)} + "
+                    f"max_new {r.max_new_tokens} cannot fit this "
+                    "engine; rejected with empty output")
+                comps.append((r, np.zeros(0, np.int32), None))
+                continue
+            # admissible later but not right now (paged: not enough
+            # free blocks yet) — keep it queued, FIFO order intact
+            if not self._can_admit(r, batch):
+                break
+            self._queue.popleft()
+            batch.append(r)
 
     # ---- admission / paging hooks (overridden by the paged engine) --------
     def _fits(self, r: Request) -> bool:
@@ -178,12 +215,15 @@ class SlotEngine:
     def _before_decode(self) -> None:
         pass                       # paged: allocate-on-decode / preemption
 
+    def _decode_tables(self) -> None:
+        pass                       # paged: the decode call's block tables
+
     def _on_slot_free(self, i: int) -> None:
         pass                       # paged: release the slot's block tables
 
     # ---- engine internals ------------------------------------------------
     def _insert_batch(self, reqs: Sequence[Request],
-                      slot_ids: Sequence[int]) -> None:
+                      slot_ids: Sequence[int], now: float = 0.0) -> None:
         m = len(reqs)
         lens = np.asarray([len(r.prompt) for r in reqs], np.int32)
         assert int(lens.max()) < self.max_len, "prompt exceeds slot length"
@@ -199,19 +239,25 @@ class SlotEngine:
         plens[:m] = lens
         for i, r in enumerate(reqs):
             toks[i, :lens[i]] = r.prompt                   # right pad
-        logits = self._prefill_insert(toks, plens, list(slot_ids))
-        if self.tracer.enabled:
+        tr = self.tracer
+        if tr.enabled:
             # one-shot joint prefill: every admitted prompt completes its
             # prefill within this iteration
             ntok = int(lens.sum())
-            self.tracer.complete(
-                "prefill",
-                self.virtual_step_cost
+            rids = [r.rid for r in reqs]
+            prefill = tr.step(
+                "prefill", virtual=self.virtual_step_cost
                 * getattr(self, "prefill_token_cost", 0.0) * ntok,
-                pid=self.replica_id, tokens=ntok, slots=m)
-            for r in reqs:
-                self.tracer.mark(r.rid, "prefill_finish",
-                                 self.tracer.now())
+                pid=self.replica_id, tokens=ntok, slots=m, rids=rids)
+        else:
+            rids, prefill = (), NULL_STEP
+        with prefill:
+            logits = self._prefill_insert(toks, plens, list(slot_ids), rids)
+        t = self._now(now)
+        for r in reqs:
+            # first-wins: a preempt-recompute prefills the prompt again
+            if r.prefill_finish_time is None:
+                r.prefill_finish_time = t
         for i, (r, slot) in enumerate(zip(reqs, slot_ids)):
             self._last_logits[slot] = np.asarray(logits[i])
             self.slots[slot] = _Slot(req=r, pos=int(lens[i]),
@@ -220,33 +266,48 @@ class SlotEngine:
             self._admit_seq += 1
 
     def _decode_iteration(self, now: float = 0.0):
-        self._before_decode()      # paged: grow tables, maybe preempt
-        toks = np.zeros((self.n_slots,), np.int32)
-        pos = np.zeros((self.n_slots,), np.int32)
-        n_dec = 0
-        for i, s in enumerate(self.slots):
-            if s.decoding:         # mid-prefill slots sit this one out
-                toks[i] = int(self._last_logits[i].argmax())
-                pos[i] = s.pos
-                n_dec += 1
-        logits = self._decode_all(toks, pos)
-        if self.tracer.enabled and n_dec:
-            # one joint decode step; its virtual cost is the flat
-            # iteration cost whatever the batch width
-            self.tracer.complete("decode", self.virtual_step_cost, ts=now,
-                                 pid=self.replica_id, tokens=n_dec)
+        tr = self.tracer
+        pid = self.replica_id
+        with (tr.step("schedule", pid=pid) if tr.enabled else NULL_STEP):
+            self._before_decode()  # paged: grow tables, maybe preempt
+            self._decode_tables()
+        with (tr.step("sample", pid=pid) if tr.enabled else NULL_STEP):
+            toks = np.zeros((self.n_slots,), np.int32)
+            pos = np.zeros((self.n_slots,), np.int32)
+            live = []
+            for i, s in enumerate(self.slots):
+                if s.decoding:     # mid-prefill slots sit this one out
+                    toks[i] = int(self._last_logits[i].argmax())
+                    pos[i] = s.pos
+                    live.append(i)
+        # one joint decode step; its virtual cost is the flat iteration
+        # cost whatever the batch width
+        with (tr.step("decode", virtual=self.virtual_step_cost, pid=pid,
+                      rows=len(live),
+                      ctx_tokens=int(sum(pos[i] + 1 for i in live)),
+                      rids=[self.slots[i].req.rid for i in live])
+              if tr.enabled else NULL_STEP):
+            logits = self._decode_all(toks, pos)
+        with (tr.step("sample", pid=pid) if tr.enabled else NULL_STEP):
+            return self._append_tokens(live, toks, logits, now)
+
+    def _append_tokens(self, live, toks, logits, now: float):
+        """Append each decoding slot's token, stamp first tokens on the
+        loop's clock, stop finished slots; returns the finished
+        (request, tokens) pairs."""
         done = []
-        for i, s in enumerate(self.slots):
-            if not s.decoding:
-                continue
+        t = None
+        for i in live:
+            s = self.slots[i]
             s.out.append(int(toks[i]))
             if len(s.out) == 1 and s.req is not None:
                 # first-wins: a preempt-recompute re-produces the token
                 # stream, but the client saw the first token at the
-                # ORIGINAL emission (trace marks share this discipline)
+                # ORIGINAL emission
                 if s.req.first_token_time is None:
-                    s.req.first_token_time = now
-                self.tracer.mark(s.req.rid, "first_token", now)
+                    if t is None:
+                        t = self._now(now)
+                    s.req.first_token_time = t
             s.pos += 1
             s.remaining -= 1
             self._last_logits[i] = logits[i]
@@ -256,7 +317,7 @@ class SlotEngine:
                 self.slots[i] = _Slot()
         return done
 
-    def _prefill_insert(self, toks, lens, slot_ids):
+    def _prefill_insert(self, toks, lens, slot_ids, rids=()):
         raise NotImplementedError
 
     def _decode_all(self, toks, pos):
@@ -311,7 +372,7 @@ class ContinuousBatcher(SlotEngine):
             lambda p, toks, lens, c: M.prefill(cfg, p, {"tokens": toks}, c,
                                                lens=lens))
 
-    def _prefill_insert(self, toks, lens, slot_ids):
+    def _prefill_insert(self, toks, lens, slot_ids, rids=()):
         m = len(slot_ids)          # toks may carry compile-padding rows > m
         scratch = M.init_cache(self.cfg, toks.shape[0], self.max_len)
         logits, scratch = self._prefill(self.params, jnp.asarray(toks),
@@ -342,8 +403,9 @@ class PipelineBatcher(SlotEngine):
                          vocab_size=pipeline.cfg.vocab_size, pad_id=pad_id,
                          virtual_step_cost=virtual_step_cost)
         self.pipeline = pipeline
+        pipeline.tracer = self.tracer
 
-    def _prefill_insert(self, toks, lens, slot_ids):
+    def _prefill_insert(self, toks, lens, slot_ids, rids=()):
         # pools allocate lazily so generate()-only engines never pay for them
         if (self.pipeline.slot_caches is None
                 or self.pipeline.n_slots != self.n_slots
@@ -485,6 +547,7 @@ class PagedPipelineBatcher(SlotEngine):
                          vocab_size=pipeline.cfg.vocab_size, pad_id=pad_id,
                          virtual_step_cost=virtual_step_cost)
         self.pipeline = pipeline
+        pipeline.tracer = self.tracer
         self.block_size = block_size
         self.max_blocks = max_len // block_size
         # paged-pool storage precision ("fp32"/"bf16"/"int8"/"fp8"; None =
@@ -811,8 +874,8 @@ class PagedPipelineBatcher(SlotEngine):
                 layer_kv=layer_kv,
                 last_logits=np.array(self._last_logits[i]),
                 kv_bytes=KVMigration.payload_bytes(layer_kv))
-            s.req.prefill_finish_time = now
-            self.tracer.mark(s.req.rid, "prefill_finish", now)
+            if s.req.prefill_finish_time is None:
+                s.req.prefill_finish_time = self._now(now)
             self.migrations += 1
             self.migrated_kv_bytes += mig.kv_bytes
             self.dispatcher.send(self, mig, now)
@@ -960,7 +1023,7 @@ class PagedPipelineBatcher(SlotEngine):
             self._bt_cache = None
         return ok
 
-    def _prefill_insert(self, toks, lens, slot_ids):
+    def _prefill_insert(self, toks, lens, slot_ids, rids=()):
         self._ensure_device_caches()
         self._bt_cache = None
         m = len(slot_ids)
@@ -983,14 +1046,18 @@ class PagedPipelineBatcher(SlotEngine):
                                           self.block_size)
                 dest[row] = t.as_array(self.max_blocks)
             stage_dest.append(dest.reshape(-1))
-        return self.pipeline.insert_slots_paged(toks, lens, slot_ids,
-                                                stage_dest)
+        tr = self.tracer
+        with (tr.step("insert", pid=self.replica_id, rows=m,
+                      tokens=int(np.sum(lens[:m])), rids=list(rids))
+              if tr.enabled else NULL_STEP):
+            return self.pipeline.insert_slots_paged(toks, lens, slot_ids,
+                                                    stage_dest)
 
     # ---- incremental insert: prefix match + deferred (chunked) prefill ----
     def _insert_batch(self, reqs: Sequence[Request],
-                      slot_ids: Sequence[int]) -> None:
+                      slot_ids: Sequence[int], now: float = 0.0) -> None:
         if not self._incremental:
-            return super()._insert_batch(reqs, slot_ids)
+            return super()._insert_batch(reqs, slot_ids, now)
         self._ensure_device_caches()
         for r, slot in zip(reqs, slot_ids):
             self._setup_slot(r, slot)
@@ -1103,7 +1170,7 @@ class PagedPipelineBatcher(SlotEngine):
             if not s.matched:
                 # match AFTER flushing so this lookup sees every block the
                 # batch's completed prompts just registered
-                self._dispatch_chunks(group)
+                self._dispatch_chunks(group, now)
                 self._match_slot(i)
             chunk = len(s.pending) if self.prefill_chunk <= 0 \
                 else min(self.prefill_chunk, len(s.pending))
@@ -1117,10 +1184,10 @@ class PagedPipelineBatcher(SlotEngine):
                 continue               # evicted itself; requeued up front
             group.append((i, chunk))
             if self.prefix_caching and chunk == len(s.pending):
-                self._dispatch_chunks(group)
-        self._dispatch_chunks(group)
+                self._dispatch_chunks(group, now)
+        self._dispatch_chunks(group, now)
 
-    def _dispatch_chunks(self, group: List) -> None:
+    def _dispatch_chunks(self, group: List, now: float = 0.0) -> None:
         """Joint (m, C) right-padded context-prefill call for the queued
         (slot, chunk) pairs: slot i's next `chunk` pending tokens run at
         absolute positions [pos, pos+chunk). Width buckets to multiples of
@@ -1152,14 +1219,23 @@ class PagedPipelineBatcher(SlotEngine):
                   else np.stack([tabs[i].as_array(self.max_blocks)
                                  for i, _ in pairs])
                   for tabs in self._tables]
-        logits = np.asarray(self.pipeline.context_slots_paged(
-            toks, lens, starts, tables))
-        if self.tracer.enabled:
+        tr = self.tracer
+        if tr.enabled:
             ntok = int(lens.sum())
-            self.tracer.complete(
+            rids = [self.slots[i].req.rid for i, _ in pairs]
+            prefill = tr.step(
                 "prefill",
-                self.virtual_step_cost * self.prefill_token_cost * ntok,
-                pid=self.replica_id, tokens=ntok, slots=m)
+                virtual=self.virtual_step_cost * self.prefill_token_cost
+                * ntok, pid=self.replica_id, tokens=ntok, slots=m,
+                rids=rids)
+            context = tr.step("context", pid=self.replica_id, rows=m,
+                              tokens=ntok, rids=rids)
+        else:
+            prefill = context = NULL_STEP
+        with prefill, context:
+            logits = np.asarray(self.pipeline.context_slots_paged(
+                toks, lens, starts, tables))
+        t = None
         for row, (i, c) in enumerate(pairs):
             s = self.slots[i]
             s.pos += c
@@ -1171,8 +1247,10 @@ class PagedPipelineBatcher(SlotEngine):
                 self._last_logits[i] = logits[row]
                 self._register_prefix(i, s)
                 self._bt_cache = None
-                self.tracer.mark(s.req.rid, "prefill_finish",
-                                 self.tracer.now())
+                if s.req.prefill_finish_time is None:
+                    if t is None:
+                        t = self._now(now)
+                    s.req.prefill_finish_time = t
 
     def _register_prefix(self, i: int, s: _Slot) -> None:
         """Index the prompt's full blocks so later prompts can alias them
@@ -1529,14 +1607,16 @@ class PagedPipelineBatcher(SlotEngine):
                     self._san.slot_access(
                         si, tabs[i].blocks, int(starts[i]) + int(qlen[i]),
                         int(starts[i]), self.block_size)
-        logits = np.asarray(self.pipeline.verify_slots_paged(
-            toks, qlen, starts, tables))
-        if self.tracer.enabled:
-            # the multi-token verification step is the iteration's target
-            # pass: flat iteration cost, like a plain decode step
-            self.tracer.complete("spec_verify", self.virtual_step_cost,
-                                 ts=now, pid=self.replica_id,
-                                 slots=len(plan))
+        # the multi-token verification step is the iteration's target
+        # pass: flat iteration cost, like a plain decode step
+        tr = self.tracer
+        with (tr.step("spec_verify", virtual=self.virtual_step_cost,
+                      pid=self.replica_id, slots=len(plan),
+                      rids=[self.slots[i].req.rid for i in plan])
+              if tr.enabled else NULL_STEP):
+            logits = np.asarray(self.pipeline.verify_slots_paged(
+                toks, qlen, starts, tables))
+        t = None
         done = []
         for i, (bonus, drafts) in plan.items():
             s = self.slots[i]
@@ -1551,8 +1631,9 @@ class PagedPipelineBatcher(SlotEngine):
             if not s.out and s.req is not None:
                 # first-wins across preempt-recompute, like plain decode
                 if s.req.first_token_time is None:
-                    s.req.first_token_time = now
-                self.tracer.mark(s.req.rid, "first_token", now)
+                    if t is None:
+                        t = self._now(now)
+                    s.req.first_token_time = t
             s.out.extend(commit)
             s.pos += len(commit)
             s.remaining -= len(commit)
@@ -1663,7 +1744,9 @@ class PagedPipelineBatcher(SlotEngine):
                         f"says host for hash {h} but the host tier "
                         "lacks it")
 
-    def _decode_all(self, toks, pos):
+    def _decode_tables(self) -> None:
+        """The decode call's per-stage block tables (cached until a table
+        mutates); KVSAN records the decode's page accesses here."""
         if self._san is not None:
             for si, tabs in enumerate(self._tables):
                 if tabs is None:
@@ -1671,8 +1754,8 @@ class PagedPipelineBatcher(SlotEngine):
                 for j, s in enumerate(self.slots):
                     if s.decoding:
                         self._san.slot_access(
-                            si, tabs[j].blocks, int(pos[j]) + 1,
-                            int(pos[j]), self.block_size)
+                            si, tabs[j].blocks, s.pos + 1, s.pos,
+                            self.block_size)
         if self._bt_cache is None:
             # rows of slots that are NOT decoding (free, or mid-prefill)
             # present an all-null table so their joint-iteration garbage
@@ -1685,4 +1768,6 @@ class PagedPipelineBatcher(SlotEngine):
                           np.zeros(self.max_blocks, np.int32)
                           for j, t in enumerate(tabs)])
                 for tabs in self._tables]
+
+    def _decode_all(self, toks, pos):
         return self.pipeline.decode_slots_paged(toks, pos, self._bt_cache)

@@ -45,7 +45,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_STEP, NULL_TRACER
 
 
 # ---------------------------------------------------------------------------
@@ -54,6 +54,8 @@ from repro.obs.trace import NULL_TRACER
 
 class WallClock:
     """Monotonic wall time, zeroed at construction. Idle waits sleep."""
+
+    virtual = False
 
     def __init__(self):
         self._t0 = time.monotonic()
@@ -73,6 +75,8 @@ class WallClock:
 class VirtualClock:
     """Deterministic simulated time. Idle waits jump; iterations advance by
     the worker-reported cost."""
+
+    virtual = True
 
     def __init__(self, t0: float = 0.0):
         self._t = t0
@@ -298,7 +302,10 @@ def run_serve_loop(workers: Sequence, requests: Sequence, *, deadline: float,
 
     ``tracer`` (repro.obs.trace.Tracer) records queue-wait and per-worker
     iteration spans against this loop's clock — pure observation, token
-    streams are identical with it on or off. ``metrics``
+    streams are identical with it on or off. A worker with a
+    ``bind_clock`` method is handed this loop's clock, on which it stamps
+    ``first_token_time`` and ``prefill_finish_time`` when the call that
+    produced them has returned. ``metrics``
     (repro.obs.metrics.MetricsRegistry) receives per-replica counter
     deltas, engine gauges (``metrics_gauges`` port) and the final
     ServeStats publication.
@@ -334,6 +341,9 @@ def run_serve_loop(workers: Sequence, requests: Sequence, *, deadline: float,
             if k not in seen:
                 wid[k] = getattr(w, "replica_id", len(wid))
                 seen[k] = (w, {c: getattr(w, c, 0) for c in counters})
+                bind = getattr(w, "bind_clock", None)
+                if bind is not None:
+                    bind(clock)
 
     _register(workers)
     # serve-level span: the sanctioned begin/end pair (repro-lint
@@ -375,17 +385,17 @@ def run_serve_loop(workers: Sequence, requests: Sequence, *, deadline: float,
         for w in list(workers):
             if not w.busy(now):
                 continue
-            done, cost = w.run_iteration(now)
+            # per-worker iteration step: on a virtual clock the clock
+            # does not advance DURING an iteration (one tick per cycle,
+            # below), so the engine-reported cost is the span's duration
+            with (tracer.step("iteration", virtual=0.0, pid=wid[id(w)])
+                  if tracer.enabled else NULL_STEP) as it:
+                done, cost = w.run_iteration(now)
+                it.set(virtual=cost, completions=len(done))
             iterations += 1
             progressed = True
             max_cost = max(max_cost, cost)
             completed.extend(done)
-            if tracer.enabled:
-                # per-worker iteration span: the clock does not advance
-                # DURING an iteration (one tick per cycle, below), so the
-                # engine-reported cost is the span's duration
-                tracer.complete("iteration", cost, ts=now,
-                                pid=wid[id(w)], completions=len(done))
         if max_cost:
             clock.tick(max_cost)
         stamp = clock.now()
@@ -419,10 +429,6 @@ def run_serve_loop(workers: Sequence, requests: Sequence, *, deadline: float,
 
     if serve_span is not None:
         tracer.end(serve_span, requests=len(pending))
-    # satellite: with tracing on, the span stream is the source of truth
-    # for first_token_time / prefill_finish_time — re-derive them (the
-    # values must equal the engines' inline stamps; tests assert it)
-    tracer.apply_marks(pending)
     stats = ServeStats.from_requests(pending, deadline,
                                      iterations=iterations)
     for c in counters:

@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ATTN, ModelConfig
 from repro.models import model as M
 from repro.models import layers, shardings
+from repro.obs.trace import NULL_STEP, NULL_TRACER
 
 
 def _rep(mesh):
@@ -342,6 +343,13 @@ class AsymmetricPipeline:
         self.stage_blocks: List[int] = []
         self.kv_dtype: Optional[str] = None
         self.kv_guard_layers: tuple = ()
+        # HexTrace: the engine driving this pipeline shares its tracer; the
+        # model-step spans (embed, stage, head, to_host) ride it
+        self.tracer = NULL_TRACER
+
+    def _step(self, name: str, **args):
+        tr = self.tracer
+        return tr.step(name, **args) if tr.enabled else NULL_STEP
 
     # ---- embedding / head on first / last stage ---------------------------
     def _embed(self, tokens, batch_extras):
@@ -557,12 +565,14 @@ class AsymmetricPipeline:
         assert self.paged_caches is not None, "call init_paged_caches first"
         m = len(slot_ids)          # rows beyond m are compile-shape padding
         b, P = tokens.shape
-        lens = jnp.asarray(lens, jnp.int32)
-        x = self._embed(jnp.asarray(tokens), {})
-        positions = jnp.arange(P)[None].repeat(b, 0)
-        valid = (jnp.arange(P)[None, :] < lens[:, None]).astype(jnp.int32)
+        with self._step("embed"):
+            lens = jnp.asarray(lens, jnp.int32)
+            x = self._embed(jnp.asarray(tokens), {})
+            positions = jnp.arange(P)[None].repeat(b, 0)
+            valid = (jnp.arange(P)[None, :]
+                     < lens[:, None]).astype(jnp.int32)
         for si, st in enumerate(self.stages):
-            with st.mesh:
+            with self._step("stage", tid=si, stage=si), st.mesh:
                 x = jax.device_put(x, _rep(st.mesh))
                 scratch = st.make_caches(b, self.slot_len)
                 x, rows = st._prefill_jit(x, scratch, positions, None,
@@ -571,8 +581,11 @@ class AsymmetricPipeline:
                     self.paged_caches[si], rows,
                     jnp.asarray(slot_ids, jnp.int32),
                     jnp.asarray(stage_dest[si], jnp.int32))
-        x_last = x[jnp.arange(m), lens[:m] - 1][:, None]
-        return np.asarray(self._head(x_last)[:, 0])
+        with self._step("head"):
+            x_last = x[jnp.arange(m), lens[:m] - 1][:, None]
+            out = self._head(x_last)[:, 0]
+        with self._step("to_host"):
+            return np.asarray(out)
 
     def context_slots_paged(self, tokens: np.ndarray, lens: np.ndarray,
                             q_start: np.ndarray,
@@ -594,18 +607,22 @@ class AsymmetricPipeline:
         assert self.paged_caches is not None, "call init_paged_caches first"
         assert context_mode_supported(self.cfg)
         m, C = tokens.shape
-        lens = jnp.asarray(lens, jnp.int32)
-        starts = jnp.asarray(q_start, jnp.int32)
-        positions = starts[:, None] + jnp.arange(C)[None]
-        x = self._embed(jnp.asarray(tokens), {})
+        with self._step("embed"):
+            lens = jnp.asarray(lens, jnp.int32)
+            starts = jnp.asarray(q_start, jnp.int32)
+            positions = starts[:, None] + jnp.arange(C)[None]
+            x = self._embed(jnp.asarray(tokens), {})
         for si, st in enumerate(self.stages):
-            with st.mesh:
+            with self._step("stage", tid=si, stage=si), st.mesh:
                 x = jax.device_put(x, _rep(st.mesh))
                 bt = jnp.asarray(stage_tables[si], jnp.int32)
                 x, self.paged_caches[si] = st._context_paged_jit(
                     x, self.paged_caches[si], positions, lens, bt)
-        x_last = x[jnp.arange(m), lens - 1][:, None]
-        return np.asarray(self._head(x_last)[:, 0])
+        with self._step("head"):
+            x_last = x[jnp.arange(m), lens - 1][:, None]
+            out = self._head(x_last)[:, 0]
+        with self._step("to_host"):
+            return np.asarray(out)
 
     def verify_slots_paged(self, tokens: np.ndarray, q_len: np.ndarray,
                            q_start: np.ndarray,
@@ -759,12 +776,16 @@ class AsymmetricPipeline:
         stage_tables[si]: (n_slots, max_blocks) int32 block table for stage
         si (rows of free slots are all-null and decode into the trash
         page). Returns (n_slots, V)."""
-        pos = jnp.asarray(positions, jnp.int32)
-        x = self._embed_decode_tokens(jnp.asarray(tokens), pos)
+        with self._step("embed"):
+            pos = jnp.asarray(positions, jnp.int32)
+            x = self._embed_decode_tokens(jnp.asarray(tokens), pos)
         for si, st in enumerate(self.stages):
-            with st.mesh:
+            with self._step("stage", tid=si, stage=si), st.mesh:
                 x = jax.device_put(x, _rep(st.mesh))
                 bt = jnp.asarray(stage_tables[si], jnp.int32)
                 x, self.paged_caches[si] = st._decode_paged_jit(
                     x, self.paged_caches[si], pos, bt)
-        return np.asarray(self._head(x)[:, 0])
+        with self._step("head"):
+            out = self._head(x)[:, 0]
+        with self._step("to_host"):
+            return np.asarray(out)

@@ -6,9 +6,14 @@ Four bars:
     preemption / disaggregated traffic is token-identical with tracing on
     or off, and two seeded ``VirtualClock`` runs export byte-identical
     Chrome traces;
-  * trace-derived request timestamps (``first_token_time``,
-    ``prefill_finish_time``) equal the engines' inline stamps, and
-    chunked-prefill TTFT equals the first decode-span start;
+  * request timestamps (``first_token_time``, ``prefill_finish_time``)
+    are the same with tracing on or off, and chunked-prefill TTFT equals
+    the first decode-span start;
+  * on a ``WallClock`` the tracer's steps measure real time, nest under
+    the step open around them, enter ``hex:`` profiler annotations and
+    record compiles; tracing off creates no span, annotation or clock
+    read on the serve path, and tokens are stamped after the call that
+    produced them;
   * ``ServeStats.merge`` / ``publish`` / ``from_metrics`` aggregate and
     round-trip counters, distributions and attainment correctly, down to
     empty/degenerate inputs;
@@ -28,9 +33,11 @@ from repro.obs.calibration import (CostCalibrator, PHASES,
 from repro.obs.metrics import (DEFAULT_BUCKETS, Histogram, MetricsRegistry,
                                phase_histograms_from_trace)
 from repro.obs.report import main as report_main
+from repro.obs import trace as T
 from repro.obs.trace import (NULL_TRACER, SPAN_NAMES, Tracer,
                              validate_chrome_trace)
-from repro.serving.loop import ServeStats, VirtualClock, run_serve_loop
+from repro.serving.loop import (ServeStats, VirtualClock, WallClock,
+                                run_serve_loop)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -97,21 +104,77 @@ def test_null_tracer_is_inert():
     NULL_TRACER.instant("preempt")
     sp = NULL_TRACER.begin("serve")
     NULL_TRACER.end(sp)
-    NULL_TRACER.mark(1, "first_token", 0.5)
-    assert NULL_TRACER.events == [] and NULL_TRACER.request_marks == {}
+    assert NULL_TRACER.events == []
 
 
-def test_marks_first_occurrence_wins():
-    tr = Tracer(VirtualClock())
-    tr.mark(1, "first_token", 2.0)
-    tr.mark(1, "first_token", 5.0)      # later stamp must not overwrite
-    class R:
-        rid = 1
-        first_token_time = None
-        prefill_finish_time = None
-    r = R()
-    tr.apply_marks([r])
-    assert r.first_token_time == 2.0 and r.prefill_finish_time is None
+def test_virtual_steps_charge_their_cost_and_name_their_parent():
+    """On a virtual clock a step records its virtual cost at entry time;
+    a step with no virtual cost records nothing; events inside a step
+    name it as their parent."""
+    clk = VirtualClock(2.0)
+    tr = Tracer(clk)
+    with tr.step("iteration", virtual=0.0, pid=3) as it:
+        with tr.step("decode", virtual=1.5, rows=2):
+            tr.instant("preempt", rid=9)
+        with tr.step("sample"):             # host work: not charged
+            pass
+        it.set(virtual=4.0, completions=1)
+    assert [e["name"] for e in tr.events] == ["preempt", "decode",
+                                              "iteration"]
+    pre, dec, it_ev = tr.events
+    assert it_ev["ts"] == dec["ts"] == 2.0
+    assert (it_ev["dur"], dec["dur"]) == (4.0, 1.5)
+    assert dec["pid"] == 3                  # inherited from the iteration
+    assert dec["args"]["parent"] == it_ev["args"]["id"]
+    assert pre["args"]["parent"] == dec["args"]["id"]
+    assert "parent" not in it_ev["args"]
+    assert it_ev["args"]["completions"] == 1
+    assert validate_chrome_trace(tr.to_chrome()) == []
+
+
+class _CountingAnnotation:
+    names = []
+
+    def __init__(self, name, **kw):
+        type(self).names.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_wall_steps_measure_time_and_enter_profiler_annotations(
+        monkeypatch):
+    _CountingAnnotation.names = []
+    monkeypatch.setattr(T, "TraceAnnotation", _CountingAnnotation)
+    tr = Tracer(WallClock())
+    with tr.step("decode", virtual=7.0, rows=1):
+        with tr.step("to_host"):
+            x = sum(range(20000))
+    assert x and _CountingAnnotation.names == ["hex:decode", "hex:to_host"]
+    inner, outer = tr.events
+    assert inner["args"]["parent"] == outer["args"]["id"]
+    assert 0 < inner["dur"] <= outer["dur"] < 1.0   # measured, not 7.0
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+def test_compile_inside_a_step_is_recorded():
+    tr = Tracer(WallClock())
+    x = jax.numpy.arange(7.0)
+    with tr.step("stage", stage=0):
+        jax.jit(lambda v: v * 3.0 + 11.0)(x).block_until_ready()
+    stage = tr.events[-1]
+    comp = [e for e in tr.events if e["name"] == "compile"]
+    assert comp and all(e["args"]["parent"] == stage["args"]["id"]
+                        for e in comp)
+    assert all(0 < e["dur"] <= stage["dur"] for e in comp)
+    # outside an open step nothing is attributed
+    n = len(tr.events)
+    jax.jit(lambda v: v * 5.0 - 2.0)(x).block_until_ready()
+    assert len(tr.events) == n
 
 
 # ---------------------------------------------------------------------------
@@ -498,6 +561,125 @@ def test_untraced_serving_emits_nothing(paged_setup):
     cfg, pipe = paged_setup
     b, _, _ = _serve_mixed(pipe, cfg, 3)
     assert b.tracer is NULL_TRACER and NULL_TRACER.events == []
+
+
+class _CountingClock(WallClock):
+    """A wall clock that counts its reads by the file that made them."""
+
+    def __init__(self):
+        super().__init__()
+        self.reads = {}
+
+    def now(self):
+        import sys
+        f = sys._getframe(1).f_code.co_filename
+        self.reads[f] = self.reads.get(f, 0) + 1
+        return super().now()
+
+    def by(self, suffix):
+        return sum(n for f, n in self.reads.items() if f.endswith(suffix))
+
+
+def _serve_wall(pipe, cfg, *, tracer=None, chunk=0, n=4):
+    from repro.serving.continuous import PagedPipelineBatcher
+    from repro.serving.request import Request
+    b = PagedPipelineBatcher(pipe(), n_slots=2, max_len=48, block_size=8,
+                             prefill_chunk=chunk)
+    if tracer is not None:
+        b.tracer = tracer
+    rng = np.random.default_rng(1)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, 12 + i
+                                               ).astype(np.int32),
+                    max_new_tokens=3, arrival=0.0) for i in range(n)]
+    clock = _CountingClock()
+    run_serve_loop([b], reqs, deadline=1e9, clock=clock, tracer=tracer)
+    return b, reqs, clock
+
+
+def test_null_tracer_serve_makes_no_span_annotation_or_clock_read(
+        paged_setup, monkeypatch):
+    """Tracing off: no step object and no profiler annotation is made, and
+    neither the tracer nor the model step reads the clock; the batcher
+    reads it only to stamp request times (at most two per request)."""
+    cfg, pipe = paged_setup
+    made = []
+
+    class CountingStep(T.Step):
+        __slots__ = ()
+
+        def __init__(self, *a, **k):
+            made.append(a[1])
+            super().__init__(*a, **k)
+
+    _CountingAnnotation.names = []
+    monkeypatch.setattr(T, "Step", CountingStep)
+    monkeypatch.setattr(T, "TraceAnnotation", _CountingAnnotation)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    b, reqs, clock = _serve_wall(pipe, cfg)
+    assert all(len(r.output) == 3 for r in reqs)
+    assert made == [] and _CountingAnnotation.names == []
+    assert clock.by("obs/trace.py") == clock.by("serving/pipeline.py") == 0
+    assert 0 < clock.by("serving/continuous.py") <= 2 * len(reqs)
+    # the same serve traced does make them (the counters see the sites)
+    _serve_wall(pipe, cfg, tracer=Tracer())
+    assert made and _CountingAnnotation.names
+
+
+def test_wall_clock_spans_nest_and_stamp_after_the_call(paged_setup):
+    """On the wall clock every span lies inside its parent, the model-step
+    spans nest under the calls, and a request's first token is stamped
+    after the decode call that appended it returned, in the sample step
+    that appended it; its prefill finish after its insert returned."""
+    cfg, pipe = paged_setup
+    tr = Tracer()
+    b, reqs, _ = _serve_wall(pipe, cfg, tracer=tr)
+    ev = {e["args"]["id"]: e for e in tr.events if "id" in
+          (e.get("args") or {})}
+    names = {e["name"] for e in ev.values()}
+    assert {"iteration", "admit", "prefill", "insert", "schedule",
+            "sample", "decode", "embed", "stage", "head",
+            "to_host"} <= names
+    assert names <= set(SPAN_NAMES)
+    eps = 1e-9
+    for e in ev.values():
+        p = ev.get(e["args"].get("parent"))
+        if p is None:
+            assert e["name"] == "iteration"
+            continue
+        assert p["ts"] - eps <= e["ts"]
+        assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + eps
+        if e["name"] in ("embed", "stage", "head", "to_host"):
+            assert p["name"] in ("insert", "decode")
+    for r in reqs:
+        ins = [e for e in ev.values() if e["name"] == "insert"
+               and r.rid in e["args"]["rids"]][0]
+        assert ins["ts"] + ins["dur"] <= r.prefill_finish_time
+        dec = min((e for e in ev.values() if e["name"] == "decode"
+                   and r.rid in e["args"]["rids"]), key=lambda e: e["ts"])
+        end = dec["ts"] + dec["dur"]
+        sample = [e for e in ev.values() if e["name"] == "sample"
+                  and e["args"]["parent"] == dec["args"]["parent"]
+                  and e["ts"] >= end][0]
+        assert end <= r.first_token_time
+        assert r.first_token_time <= sample["ts"] + sample["dur"]
+    assert validate_chrome_trace(tr.to_chrome()) == []
+
+
+def test_wall_clock_context_spans_carry_rows_and_tokens(paged_setup):
+    """Chunked prefill runs through ``context`` calls inside ``prefill``
+    steps; each carries its rows, prompt tokens and request ids."""
+    cfg, pipe = paged_setup
+    tr = Tracer()
+    b, reqs, _ = _serve_wall(pipe, cfg, tracer=tr, chunk=8, n=2)
+    ctx = [e for e in tr.events if e["name"] == "context"]
+    pre = {e["args"]["id"]: e for e in tr.events if e["name"] == "prefill"}
+    assert ctx and all(e["args"]["parent"] in pre for e in ctx)
+    assert sum(e["args"]["tokens"] for e in ctx) == sum(
+        len(r.prompt) for r in reqs)
+    for e in ctx:
+        assert e["args"]["rows"] == len(e["args"]["rids"])
+        assert pre[e["args"]["parent"]]["args"]["tokens"] == \
+            e["args"]["tokens"]
 
 
 # ---------------------------------------------------------------------------
