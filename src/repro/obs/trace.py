@@ -76,9 +76,12 @@ SPAN_NAMES = (
     "insert",            # model step: one-shot joint prefill call
     "context",           # model step: paged context-prefill call
     "decode",            # model step: one decode call over the batch
-    "embed",             # model step: token embedding gather
+                         # (paged: args.programs, the programs it ran)
+    "embed",             # model step: token embedding gather (insert,
+                         # context; the paged decode gathers in `stage`)
     "stage",             # model step: one stage's upload + jit dispatch
-    "head",              # model step: final norm + output head
+    "head",              # model step: final norm + output head (insert,
+                         # context; the paged decode's last `stage`)
     "to_host",           # model step: blocking copy of logits to host
     "compile",           # backend compile inside an open step
     "spec_propose",      # draft tokens proposed

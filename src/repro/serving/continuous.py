@@ -221,6 +221,9 @@ class SlotEngine:
     def _on_slot_free(self, i: int) -> None:
         pass                       # paged: release the slot's block tables
 
+    def _decode_call_args(self) -> dict:
+        return {}                  # paged: the programs the call dispatched
+
     # ---- engine internals ------------------------------------------------
     def _insert_batch(self, reqs: Sequence[Request],
                       slot_ids: Sequence[int], now: float = 0.0) -> None:
@@ -286,8 +289,10 @@ class SlotEngine:
                       rows=len(live),
                       ctx_tokens=int(sum(pos[i] + 1 for i in live)),
                       rids=[self.slots[i].req.rid for i in live])
-              if tr.enabled else NULL_STEP):
+              if tr.enabled else NULL_STEP) as call:
             logits = self._decode_all(toks, pos)
+            if tr.enabled:
+                call.set(**self._decode_call_args())
         with (tr.step("sample", pid=pid) if tr.enabled else NULL_STEP):
             return self._append_tokens(live, toks, logits, now)
 
@@ -1771,3 +1776,6 @@ class PagedPipelineBatcher(SlotEngine):
 
     def _decode_all(self, toks, pos):
         return self.pipeline.decode_slots_paged(toks, pos, self._bt_cache)
+
+    def _decode_call_args(self) -> dict:
+        return {"programs": self.pipeline.decode_programs}

@@ -113,6 +113,34 @@ def _stage_decode_paged(cfg, kinds, lps, x, caches, pos, block_tables):
     return x, new_caches
 
 
+def _decode_embed(cfg, hp, tokens, positions):
+    """Single-token decode embedding (b, 1, d) of tokens (b,), with the
+    sinusoidal positions where the architecture uses them."""
+    x = M._embed(cfg, hp, tokens[:, None])
+    if cfg.is_encoder_decoder and cfg.rope_theta == 0.0:
+        x = x + layers.sinusoidal_positions(positions[:, None],
+                                            cfg.d_model).astype(x.dtype)
+    return x
+
+
+def _stage_decode_paged_call(cfg, kinds, is_first, is_last, lps, hp, x,
+                             caches, pos, block_tables):
+    """The whole paged decode call of a first and/or last stage: the
+    first takes token ids (b,) and gathers their embeddings, the last
+    ends with the final norm and head and returns logits (b, V)."""
+    if is_first:
+        x = _decode_embed(cfg, hp, x, pos)
+    x, caches = _stage_decode_paged(cfg, kinds, lps, x, caches, pos,
+                                    block_tables)
+    if is_last:
+        # the head reads the activations rounded to their dtype, as the
+        # op-by-op head did: fused into the last residual add, the norm
+        # would see them at excess precision and move near-tied tokens
+        x = jax.lax.optimization_barrier(x)
+        x = M._head(cfg, hp, x)[:, 0]
+    return x, caches
+
+
 def _stage_context_paged(cfg, kinds, lps, x, caches, positions, q_len,
                          block_tables):
     new_caches = []
@@ -169,10 +197,7 @@ def _stage_scatter_rows_paged(pools, rows, slot_ids, dest):
 
 
 @functools.lru_cache(maxsize=None)
-def stage_programs(cfg: ModelConfig, kinds: tuple):
-    """The jitted programs of a stage whose layers have ``kinds``, shared
-    by every stage of that shape. Each takes the stage's per-layer params
-    first; the caches that follow are donated (updated in place)."""
+def _layer_programs(cfg: ModelConfig, kinds: tuple):
     bodies = {
         "prefill": (partial(_stage_seq, cfg, kinds, mode="prefill"), ()),
         "decode": (partial(_stage_decode, cfg, kinds), (2,)),
@@ -187,6 +212,31 @@ def stage_programs(cfg: ModelConfig, kinds: tuple):
     return types.SimpleNamespace(**{
         name: jax.jit(fn, donate_argnums=d)  # repro: noqa[jit-retrace]
         for name, (fn, d) in bodies.items()})
+
+
+@functools.lru_cache(maxsize=None)
+def stage_programs(cfg: ModelConfig, kinds: tuple, is_first: bool = False,
+                   is_last: bool = False):
+    """The jitted programs of a stage whose layers have ``kinds``, shared
+    by every stage of that shape. Each takes the stage's per-layer params
+    first; the caches that follow are donated (updated in place).
+
+    A first or last stage (``is_first``/``is_last``) also gets
+    ``decode_paged_call(lps, head_params, x, caches, pos, block_tables)``:
+    ``decode_paged`` with the embedding gather of the token ids ``x`` in
+    front (first stage) and the final norm and head behind (last stage),
+    so the paged decode call runs one program per stage. Middle stages
+    run ``decode_paged``, activations in and out. The layer programs are
+    the same objects whatever the stage's place."""
+    progs = _layer_programs(cfg, kinds)
+    if not (is_first or is_last):
+        return progs
+    call = partial(_stage_decode_paged_call, cfg, kinds, is_first, is_last)
+    # memoized per stage shape and place, never per iteration
+    return types.SimpleNamespace(
+        **vars(progs),
+        decode_paged_call=jax.jit(  # repro: noqa[jit-retrace]
+            call, donate_argnums=(3,)))
 
 
 class StageExecutor:
@@ -242,7 +292,8 @@ class StageExecutor:
                                if n in names},
                     out_shardings=_rep(self.mesh))(key)
 
-        progs = stage_programs(cfg, tuple(self.kinds))
+        progs = self._progs = stage_programs(
+            cfg, tuple(self.kinds), is_first=is_first, is_last=is_last)
         lps = self.layer_params
         self._prefill_jit = partial(progs.prefill, lps)
         self._decode_jit = partial(progs.decode, lps)
@@ -257,6 +308,16 @@ class StageExecutor:
     @property
     def has_attn(self) -> bool:
         return ATTN in self.kinds
+
+    def decode_paged_call(self, x, caches, pos, block_tables):
+        """This stage's part of a paged decode call, as one program: token
+        ids in on the first stage, activations otherwise; logits out on
+        the last stage, activations otherwise. Returns (out, caches)."""
+        if self.is_first or self.is_last:
+            return self._progs.decode_paged_call(
+                self.layer_params, self.head_params, x, caches, pos,
+                block_tables)
+        return self._decode_paged_jit(x, caches, pos, block_tables)
 
     # ---- cache ------------------------------------------------------------
     def _alloc(self, name, make):
@@ -343,6 +404,8 @@ class AsymmetricPipeline:
         self.stage_blocks: List[int] = []
         self.kv_dtype: Optional[str] = None
         self.kv_guard_layers: tuple = ()
+        # device programs the last decode_slots_paged call dispatched
+        self.decode_programs = 0
         # HexTrace: the engine driving this pipeline shares its tracer; the
         # model-step spans (embed, stage, head, to_host) ride it
         self.tracer = NULL_TRACER
@@ -364,12 +427,7 @@ class AsymmetricPipeline:
         return x
 
     def _head(self, x):
-        sl = self.stages[-1]
-        hp = sl.head_params
-        x = M._norm(self.cfg, hp["final_norm"], x)
-        if self.cfg.tie_embeddings:
-            return x @ hp["embed"].T
-        return M.mm(x, hp["lm_head"])
+        return M._head(self.cfg, self.stages[-1].head_params, x)
 
     # ---- public API --------------------------------------------------------
     def prefill(self, tokens: np.ndarray, *, kv_start=None, max_new: int = 32,
@@ -410,16 +468,12 @@ class AsymmetricPipeline:
         return np.asarray(self._head(x[:, -1:, :])[:, 0])
 
     def _embed_decode_tokens(self, tokens, positions):
-        """Single-token decode embedding (b,1,d): embed lookup + family
-        scaling + sinusoidal positions where the architecture uses them."""
-        cfg = self.cfg
-        x = self.stages[0].head_params["embed"][tokens[:, None]]
-        if cfg.family == "vlm":
-            x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
-        if cfg.is_encoder_decoder and cfg.rope_theta == 0.0:
-            x = x + layers.sinusoidal_positions(positions[:, None],
-                                                cfg.d_model).astype(x.dtype)
-        return x
+        """Single-token decode embedding (b,1,d), op by op: the contiguous
+        ``decode_step`` and ``decode_slots`` run it. The paged decode call
+        gathers inside the first stage's ``decode_paged_call`` program
+        instead (``_decode_embed``)."""
+        return _decode_embed(self.cfg, self.stages[0].head_params, tokens,
+                             positions)
 
     def decode_step(self, tokens: np.ndarray):
         """tokens (b,) -> next-position logits (b, V)."""
@@ -775,17 +829,26 @@ class AsymmetricPipeline:
         """One decode iteration over ALL slots through the paged caches.
         stage_tables[si]: (n_slots, max_blocks) int32 block table for stage
         si (rows of free slots are all-null and decode into the trash
-        page). Returns (n_slots, V)."""
-        with self._step("embed"):
-            pos = jnp.asarray(positions, jnp.int32)
-            x = self._embed_decode_tokens(jnp.asarray(tokens), pos)
+        page). Returns host logits (n_slots, V) in the head's dtype.
+
+        Each stage runs ONE program (``StageExecutor.decode_paged_call``):
+        the first gathers the tokens' embeddings inside it and the last
+        ends with the final norm and head, so nothing runs op by op around
+        them; the tokens, positions and tables go into the call as host
+        arrays. ``decode_programs`` counts the programs the call
+        dispatched. The insert, context and verify calls and the
+        contiguous decode still embed and apply the head op by op
+        (``_embed``, ``_embed_decode_tokens``, ``_head``)."""
+        x = np.asarray(tokens, np.int32)
+        pos = np.asarray(positions, np.int32)
+        self.decode_programs = 0
         for si, st in enumerate(self.stages):
             with self._step("stage", tid=si, stage=si), st.mesh:
-                x = jax.device_put(x, _rep(st.mesh))
-                bt = jnp.asarray(stage_tables[si], jnp.int32)
-                x, self.paged_caches[si] = st._decode_paged_jit(
-                    x, self.paged_caches[si], pos, bt)
-        with self._step("head"):
-            out = self._head(x)[:, 0]
+                if si:
+                    x = jax.device_put(x, _rep(st.mesh))
+                x, self.paged_caches[si] = st.decode_paged_call(
+                    x, self.paged_caches[si], pos,
+                    np.asarray(stage_tables[si], np.int32))
+                self.decode_programs += 1
         with self._step("to_host"):
-            return np.asarray(out)
+            return np.asarray(x)

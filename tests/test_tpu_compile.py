@@ -97,6 +97,28 @@ def test_paged_decode_layer_compiles_on_one_chip(topo):
     assert "tpu_custom_call" not in compiled.as_text()   # the XLA path
 
 
+def test_paged_decode_call_compiles_on_one_chip(topo):
+    """The whole paged decode call of a one-stage pipeline: one layer with
+    the embedding gather of the token ids in front and the final norm and
+    head behind, in one program."""
+    mesh = _mesh(topo, 1)
+    names = PL.head_names(CFG, is_first=True, is_last=True)
+    head = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, mesh),
+        {n: s for n, s in jax.eval_shape(
+            partial(M.init_head_params, CFG), KEY).items() if n in names})
+    progs = PL.stage_programs(CFG, (ATTN,), is_first=True, is_last=True)
+    compiled = progs.decode_paged_call.lower(
+        _layer_shapes(mesh, 1), head, _sds((N_SLOTS,), I32, mesh),
+        _pools(mesh), _sds((N_SLOTS,), I32, mesh),
+        _sds((N_SLOTS, MAX_LEN // BLOCK), I32, mesh)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES / 2
+    assert "tpu_custom_call" not in compiled.as_text()   # the XLA path
+    logits = compiled.out_info[0]
+    assert logits.shape == (N_SLOTS, CFG.vocab_size)
+    assert logits.dtype == jnp.dtype(CFG.dtype)
+
+
 def test_insert_prefill_layer_compiles_on_one_chip(topo):
     mesh = _mesh(topo, 1)
     progs = PL.stage_programs(CFG, (ATTN,))
