@@ -75,8 +75,11 @@ SPAN_NAMES = (
     "prefill",           # prompt tokens computed this iteration (chunk)
     "insert",            # model step: one-shot joint prefill call
     "context",           # model step: paged context-prefill call
+                         # (args.starts, each chunk's first position;
+                         # args.tp, the devices of each stage)
     "decode",            # model step: one decode call over the batch
-                         # (paged: args.programs, the programs it ran)
+                         # (paged: args.programs, the programs it ran;
+                         # args.tp, the devices of each stage)
     "embed",             # model step: token embedding gather (insert,
                          # context; the paged decode gathers in `stage`)
     "stage",             # model step: one stage's upload + jit dispatch
@@ -87,7 +90,8 @@ SPAN_NAMES = (
     "spec_propose",      # draft tokens proposed
     "spec_verify",       # multi-token verification step
     "spec_rollback",     # rejected-draft KV truncation
-    "preempt",           # slot evicted (instant) + recompute accounted
+    "preempt",           # slot evicted (instant; args.tokens, the cached
+                         # tokens thrown away) + recompute accounted
     "host_spill",        # device -> host page demotion
     "host_promote",      # host -> device page swap-in
     "prefix_fetch",      # cluster prefix-directory block migration

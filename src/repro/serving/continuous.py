@@ -101,6 +101,7 @@ class SlotEngine:
         self._last_logits = np.zeros((n_slots, vocab_size), np.float32)
         self.rejected = 0          # oversized requests turned away
         self.preemptions = 0       # paged: slots recomputed after eviction
+        self.recomputed_tokens = 0  # paged: cached tokens evictions threw away
         self._admit_seq = 0
 
     @property
@@ -553,6 +554,8 @@ class PagedPipelineBatcher(SlotEngine):
                          virtual_step_cost=virtual_step_cost)
         self.pipeline = pipeline
         pipeline.tracer = self.tracer
+        # devices of each stage: the model-step spans' `tp`
+        self._stage_tp = [st.tp for st in pipeline.stages]
         self.block_size = block_size
         self.max_blocks = max_len // block_size
         # paged-pool storage precision ("fp32"/"bf16"/"int8"/"fp8"; None =
@@ -1196,17 +1199,21 @@ class PagedPipelineBatcher(SlotEngine):
         """Joint (m, C) right-padded context-prefill call for the queued
         (slot, chunk) pairs: slot i's next `chunk` pending tokens run at
         absolute positions [pos, pos+chunk). Width buckets to multiples of
-        16 so mixed chunk lengths compile O(log) shapes. Clears `group`."""
+        16 and the row count to the next power of two (capped at n_slots),
+        so mixed chunk lengths and group sizes compile O(log) shapes; pad
+        rows have length 0 and all-null tables, so they write only the
+        trash page. Clears `group`."""
         pairs = [(i, c) for i, c in group
                  if not self.slots[i].free]   # a later prepare may preempt
         group.clear()
         if not pairs:
             return
         m = len(pairs)
+        rows = min(1 << (m - 1).bit_length(), self.n_slots)
         C = min(-(-max(c for _, c in pairs) // 16) * 16, self.max_len - 1)
-        toks = np.full((m, C), self.pad_id, np.int32)
-        lens = np.zeros(m, np.int32)
-        starts = np.zeros(m, np.int32)
+        toks = np.full((rows, C), self.pad_id, np.int32)
+        lens = np.zeros(rows, np.int32)
+        starts = np.zeros(rows, np.int32)
         for row, (i, c) in enumerate(pairs):
             s = self.slots[i]
             toks[row, :c] = s.pending[:c]
@@ -1220,10 +1227,13 @@ class PagedPipelineBatcher(SlotEngine):
                     self._san.slot_access(
                         si, tabs[i].blocks, int(starts[row]) + c,
                         int(starts[row]), self.block_size)
-        tables = [np.zeros((m, self.max_blocks), np.int32) if tabs is None
-                  else np.stack([tabs[i].as_array(self.max_blocks)
-                                 for i, _ in pairs])
-                  for tabs in self._tables]
+        tables = []
+        for tabs in self._tables:
+            t = np.zeros((rows, self.max_blocks), np.int32)
+            if tabs is not None:
+                for row, (i, _) in enumerate(pairs):
+                    t[row] = tabs[i].as_array(self.max_blocks)
+            tables.append(t)
         tr = self.tracer
         if tr.enabled:
             ntok = int(lens.sum())
@@ -1234,7 +1244,8 @@ class PagedPipelineBatcher(SlotEngine):
                 * ntok, pid=self.replica_id, tokens=ntok, slots=m,
                 rids=rids)
             context = tr.step("context", pid=self.replica_id, rows=m,
-                              tokens=ntok, rids=rids)
+                              tokens=ntok, rids=rids,
+                              starts=starts[:m].tolist(), tp=self._stage_tp)
         else:
             prefill = context = NULL_STEP
         with prefill, context:
@@ -1514,15 +1525,18 @@ class PagedPipelineBatcher(SlotEngine):
         if self._proposer is not None:
             self._proposer.release(i)
         # recompute: the request restarts from its prompt (greedy decode
-        # regenerates the same prefix), at the FRONT of the queue
+        # regenerates the same prefix), at the FRONT of the queue. The
+        # slot's s.pos cached tokens (prompt, and outputs once decoding)
+        # are thrown away and computed again.
         self._queue.appendleft(s.req)
         self.slots[i] = _Slot()
         self.preemptions += 1
+        self.recomputed_tokens += s.pos
         if self.tracer.enabled:
             # the recompute itself shows up as this request's next
             # prefill span; the eviction is the instant
             self.tracer.instant("preempt", pid=self.replica_id,
-                                rid=s.req.rid, slot=i, pos=s.pos)
+                                rid=s.req.rid, slot=i, tokens=s.pos)
 
     def _on_slot_free(self, i: int) -> None:
         for tabs in self._tables:
@@ -1778,4 +1792,5 @@ class PagedPipelineBatcher(SlotEngine):
         return self.pipeline.decode_slots_paged(toks, pos, self._bt_cache)
 
     def _decode_call_args(self) -> dict:
-        return {"programs": self.pipeline.decode_programs}
+        return {"programs": self.pipeline.decode_programs,
+                "tp": self._stage_tp}

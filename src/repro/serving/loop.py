@@ -104,6 +104,7 @@ class ServeStats:
     queue_delays: List[float] = dataclasses.field(default_factory=list)
     rejected: int = 0              # oversized requests turned away
     preemptions: int = 0           # paged slots evicted + recomputed
+    recomputed_tokens: int = 0     # cached tokens those evictions threw away
     dropped: int = 0               # stranded: never finished (no finish_time)
     # prefix-cache counters (PagedPipelineBatcher with prefix_caching=True)
     prefix_lookups: int = 0        # admissions that consulted the index
@@ -318,7 +319,8 @@ def run_serve_loop(workers: Sequence, requests: Sequence, *, deadline: float,
     idx = 0
     iterations = 0
     # workers persist across serve() calls: report this replay's deltas.
-    counters = ("rejected", "preemptions", "prefix_lookups", "prefix_hits",
+    counters = ("rejected", "preemptions", "recomputed_tokens",
+                "prefix_lookups", "prefix_hits",
                 "prefix_hit_tokens", "prefill_tokens", "cow_copies",
                 "migrations", "migrated_kv_bytes", "spec_steps",
                 "spec_proposed", "spec_accepted", "spec_tokens",

@@ -682,6 +682,29 @@ def test_wall_clock_context_spans_carry_rows_and_tokens(paged_setup):
             e["args"]["tokens"]
 
 
+def test_wall_clock_model_step_spans_carry_devices_and_chunk_starts(
+        paged_setup):
+    """The ``decode`` and ``context`` calls carry ``tp``, the devices of
+    each stage, and each ``context`` call the first position of each of
+    its chunks, aligned with its request ids: one request's chunks start
+    at 0, 8, 16, ... in turn."""
+    cfg, pipe = paged_setup
+    tr = Tracer()
+    b, reqs, _ = _serve_wall(pipe, cfg, tracer=tr, chunk=8, n=2)
+    calls = [e for e in tr.events if e["name"] in ("decode", "context")]
+    assert {e["name"] for e in calls} == {"decode", "context"}
+    assert all(e["args"]["tp"] == [1, 1] for e in calls)
+    starts = {r.rid: [] for r in reqs}
+    for e in calls:
+        if e["name"] == "context":
+            a = e["args"]
+            assert len(a["starts"]) == len(a["rids"]) == a["rows"]
+            for rid, start in zip(a["rids"], a["starts"]):
+                starts[rid].append(start)
+    for r in reqs:
+        assert starts[r.rid] == list(range(0, len(r.prompt), 8))
+
+
 # ---------------------------------------------------------------------------
 # Report CLI
 # ---------------------------------------------------------------------------

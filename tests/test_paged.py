@@ -286,6 +286,43 @@ def test_paged_preemption_recomputes_identically():
         assert list(rc.output) == list(rp.output), rc.rid
 
 
+@pytest.mark.parametrize("prefill_chunk", [0, 8])
+def test_recomputed_tokens_count_each_thrown_away_token_once(prefill_chunk):
+    """Preemption throws away a slot's cached tokens (its prompt, and its
+    outputs once decoding) and computes them again. The tokens computed
+    in all (prefilled, and decoded by live rows) exceed those of a pool
+    that never preempts by exactly ``recomputed_tokens``, and each
+    ``preempt`` instant carries the tokens it threw away."""
+    from repro.obs.trace import Tracer
+    cfg = get_config("granite-8b").reduced()
+    params = M.init_params(cfg, KEY)
+
+    def serve(stage_blocks):
+        rng = np.random.RandomState(3)
+        reqs = [Request(rid=i, prompt=rng.randint(
+                    0, cfg.vocab_size, size=6 + 5 * i).astype(np.int32),
+                        max_new_tokens=14, arrival=0.0) for i in range(3)]
+        eng = PagedPipelineBatcher(
+            _pipe(cfg, params), n_slots=3, max_len=40, block_size=8,
+            stage_blocks=stage_blocks, admit_headroom=2,
+            prefill_chunk=prefill_chunk)
+        eng.tracer = Tracer()
+        stats = eng.serve(reqs, deadline=1e9)
+        ev = eng.tracer.events
+        work = stats.prefill_tokens + sum(
+            e["args"]["rows"] for e in ev if e["name"] == "decode")
+        thrown = [e["args"]["tokens"] for e in ev if e["name"] == "preempt"]
+        return stats, work, thrown, [r.output for r in reqs]
+
+    full, work_full, _, out_full = serve(None)
+    small, work_small, thrown, out_small = serve([9, 9])
+    assert full.preemptions == full.recomputed_tokens == 0
+    assert small.preemptions == len(thrown) > 0
+    assert small.recomputed_tokens == sum(thrown) > 0
+    assert work_small - work_full == small.recomputed_tokens
+    assert [list(o) for o in out_small] == [list(o) for o in out_full]
+
+
 def test_oversized_request_rejected_and_counted():
     cfg = get_config("granite-8b").reduced()
     params = M.init_params(cfg, KEY)

@@ -225,8 +225,41 @@ def test_ops_context_xla_matches_gathered_oracle():
     assert np.array_equal(np.asarray(o), np.asarray(want))
 
 
-# ---------------------------------------------------------------------------
-# Model level: chunked context prefill == one-shot prefill
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("group", [1, 2, 3, 5])
+def test_ops_context_xla_groups_rows_and_skips_pad_groups(monkeypatch, group,
+                                                          quant):
+    """Rows whose score tiles exceed the budget run in groups of `group`
+    (5 = all at once): real rows match the gathered oracle, and a group of
+    pad rows (kv_len 0) is skipped, reading zeros."""
+    b, C, hq, hkv, d = 5, 4, 4, 2, 16
+    bs, n_blocks = 8, 12
+    monkeypatch.setattr(ops, "CONTEXT_SCORE_BYTES",
+                        group * 4 * C * 4 * bs * hq)
+    q = rn(1, b, C, hq, d)
+    kp = rn(2, n_blocks, bs, hkv, d)
+    vp = rn(3, n_blocks, bs, hkv, d)
+    bt = jnp.asarray(np.array([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 0, 0],
+                               [0, 0, 0, 0], [0, 0, 0, 0]], np.int32))
+    q_start = jnp.array([10, 0, 3, 0, 0])
+    kv_len = jnp.array([14, 4, 7, 0, 0])
+    kw = dict(q_start=q_start, kv_len=kv_len)
+    if quant:
+        ks = jnp.abs(rn(4, n_blocks, bs, hkv)) + 0.5
+        vs = jnp.abs(rn(5, n_blocks, bs, hkv)) + 0.5
+        o = ops.paged_context_attention(q, kp, vp, bt, k_scale=ks,
+                                        v_scale=vs, **kw)
+        want = ref.paged_context_attention_quant_ref(q, kp, vp, ks, vs, bt,
+                                                     **kw)
+    else:
+        o = ops.paged_context_attention(q, kp, vp, bt, **kw)
+        want = ref.paged_context_attention_ref(q, kp, vp, bt, **kw)
+    o = np.asarray(o)
+    assert o.shape == (b, C, hq, d)
+    np.testing.assert_allclose(o[:3], np.asarray(want)[:3], rtol=1e-6,
+                               atol=1e-6)
+    if group < b:       # rows 3 and 4 share no group with a real row
+        assert not o[4].any()
 # ---------------------------------------------------------------------------
 
 def test_prefill_paged_context_chunked_equals_one_shot():
@@ -418,6 +451,44 @@ def test_chunked_prefill_fairness_long_prompt_does_not_stall_decode():
     assert list(one[1].output) == list(chunked[1].output)
     # the short request's TTFT improves; the giant prompt pays the chunks
     assert chunked[1].first_token_time < one[1].first_token_time
+
+
+def test_chunk_calls_pad_rows_to_a_power_of_two():
+    """Chunks of several slots run as one context call whose row count is
+    the next power of two (at most n_slots), so a stage compiles one
+    context program per power of two instead of one per group size; the
+    pad rows change no request's tokens."""
+    cfg = get_config("granite-8b").reduced()
+    params = M.init_params(cfg, KEY)
+    dev = jax.devices()[0]
+    L = cfg.num_layers
+
+    def mk():
+        rng = np.random.RandomState(9)
+        return [Request(rid=i, prompt=rng.randint(
+                    0, cfg.vocab_size, 12 + 9 * i).astype(np.int32),
+                        max_new_tokens=4, arrival=0.0) for i in range(5)]
+
+    kw = dict(n_slots=6, max_len=64, block_size=8)
+    one = mk()
+    PagedPipelineBatcher(AsymmetricPipeline(cfg, params, [1, L - 1],
+                                            [[dev], [dev]]),
+                         **kw).serve(one, deadline=1e9)
+    pipe = AsymmetricPipeline(cfg, params, [1, L - 1], [[dev], [dev]])
+    shapes, call = [], pipe.context_slots_paged
+
+    def spy(tokens, lens, q_start, tables):
+        shapes.append((tokens.shape[0], int(np.count_nonzero(lens))))
+        return call(tokens, lens, q_start, tables)
+    pipe.context_slots_paged = spy
+    chunked = mk()
+    PagedPipelineBatcher(pipe, prefill_chunk=8, **kw).serve(chunked,
+                                                            deadline=1e9)
+    assert {rows for rows, _ in shapes} <= {1, 2, 4, 6}
+    assert any(real == 5 for _, real in shapes)   # 5 real rows, 6 in all
+    assert any(real == 3 for _, real in shapes)   # 3 real rows, 4 in all
+    for a, b in zip(one, chunked):
+        assert list(a.output) == list(b.output), a.rid
 
 
 def test_prefix_cache_eviction_under_pool_pressure():

@@ -157,6 +157,67 @@ def test_tp2_layer_compiles_with_a_collective(topo):
     assert compiled.memory_analysis().temp_size_in_bytes < POOL_BYTES / 4
 
 
+# ---- The whole granite-8b on a v5e 2x2 host at TP 4: the long-document
+# cell's shapes (16 slots of 17,424 tokens, chunks of 512), one layer.
+
+DOCS_SLOTS, DOCS_MAX_LEN, DOCS_CHUNK = 16, 17_424, 512
+DOCS_BLOCKS = DOCS_SLOTS * DOCS_MAX_LEN // BLOCK + 1     # every slot full
+
+
+def _docs_program(topo, which):
+    from repro.configs.granite_8b import CONFIG as FULL
+    mesh = _mesh(topo, 4)
+    shapes = jax.eval_shape(partial(M.init_layer_params, FULL, i=0), KEY)
+    lps = [jax.tree.map(lambda s, p: _sds(s.shape, s.dtype, mesh, p),
+                        shapes, PL.layer_specs(FULL, 0, shapes, 4))]
+    kv = jax.ShapeDtypeStruct(
+        (DOCS_BLOCKS, BLOCK, FULL.num_kv_heads, FULL.head_dim_), BF16)
+    pools = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        [{"k": kv, "v": kv}], PL.cache_shardings(FULL, mesh,
+                                                 [{"k": kv, "v": kv}]))
+    tables = _sds((DOCS_SLOTS, DOCS_MAX_LEN // BLOCK), I32, mesh)
+    n = DOCS_SLOTS
+    if which == "decode_call":
+        names = PL.head_names(FULL, is_first=True, is_last=True)
+        head = jax.tree.map(
+            lambda s: _sds(s.shape, s.dtype, mesh),
+            {k: s for k, s in jax.eval_shape(
+                partial(M.init_head_params, FULL), KEY).items()
+             if k in names})
+        progs = PL.stage_programs(FULL, (ATTN,), is_first=True,
+                                  is_last=True)
+        return progs.decode_paged_call.lower(
+            lps, head, _sds((n,), I32, mesh), pools, _sds((n,), I32, mesh),
+            tables).compile()
+    progs = PL.stage_programs(FULL, (ATTN,))
+    return progs.context_paged.lower(
+        lps, _sds((n, DOCS_CHUNK, FULL.d_model), BF16, mesh), pools,
+        _sds((n, DOCS_CHUNK), I32, mesh), _sds((n,), I32, mesh),
+        tables).compile()
+
+
+@pytest.mark.parametrize("which", ["decode_call", "context_chunk"])
+def test_tp4_long_document_layer_compiles(topo, which):
+    """One layer over the four chips with every slot's pages in the pool:
+    the row-parallel projections all-reduce, the pools are updated in
+    place, and the step's temporaries stay far below what the pool
+    leaves free (about 1.9 GB a chip). Scored all rows at once, a chunk's
+    float32 scores would take 4.25 GiB a chip; one row at a time they
+    take 272 MiB."""
+    compiled = _docs_program(topo, which)
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    assert "tpu_custom_call" not in text                 # the XLA path
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2 ** 29
+    pool = 2 * DOCS_BLOCKS * BLOCK * 2 * 128 * 2          # K and V, 2 heads
+    assert m.alias_size_in_bytes >= pool
+    if which == "decode_call":
+        logits = compiled.out_info[0]
+        assert logits.shape == (DOCS_SLOTS, 49152)
+
+
 # ---- Pallas kernels at granite-8b widths: refused by the chip's compiler.
 # The serving path runs the XLA ops; the PR that repairs a kernel flips its
 # mark.
