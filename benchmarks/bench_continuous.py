@@ -2,12 +2,10 @@
 (the paper's Appendix-D limitation). Same replicas, same requests; latency
 comes from the measured CPU engine (relative numbers are what matter).
 
-Two comparisons:
-  * single monolithic replica (the original beyond-paper extension);
-  * a MULTI-STAGE asymmetric pipeline replica — the paper's actual
-    artifact — served statically vs at iteration granularity through the
-    shared loop. A JSON row records this path so the perf trajectory
-    tracks it across PRs.
+MULTI-STAGE asymmetric pipeline replicas — the paper's actual artifact —
+served statically vs at iteration granularity (the paged continuous
+engine) through the shared loop. A JSON row records this path so the
+perf trajectory tracks it across PRs.
 """
 from __future__ import annotations
 
@@ -21,7 +19,6 @@ from benchmarks.common import emit
 from repro.configs import get_config
 from repro.core.plan import Assignment, PipelinePlan, StagePlan
 from repro.models import model as M
-from repro.serving.continuous import ContinuousBatcher
 from repro.serving.engine import InferenceEngine
 from repro.serving.request import synth_workload
 
@@ -44,22 +41,6 @@ def run() -> None:
         return synth_workload(rate=12.0, duration=1.0, vocab=cfg.vocab_size,
                               prompt_len=8, prompt_jitter=6, out_len=6,
                               seed=seed)
-
-    # ---- monolithic replica: static engine vs slot batcher ---------------
-    asg = Assignment([PipelinePlan([StagePlan([0], cfg.num_layers)],
-                                   cost=0.1, bottleneck=0.1)])
-    eng = InferenceEngine(cfg, asg, params=params, max_batch=4,
-                          policy="static")
-    st = eng.serve(workload(3), deadline=60.0)
-    emit("continuous/static", np.mean(st.latencies) * 1e6,
-         f"p50={np.percentile(st.latencies, 50):.2f}s thpt={st.throughput:.2f}")
-
-    cb = ContinuousBatcher(cfg, params, n_slots=4, max_len=64)
-    ct = cb.serve(workload(3), deadline=60.0, realtime=True)
-    emit("continuous/continuous", np.mean(ct.latencies) * 1e6,
-         f"p50={np.percentile(ct.latencies, 50):.2f}s thpt={ct.throughput:.2f}")
-    emit("continuous/latency_gain", 0.0,
-         f"{np.mean(st.latencies)/np.mean(ct.latencies):.2f}x lower mean latency")
 
     # ---- multi-stage asymmetric replicas through the unified router ------
     L = cfg.num_layers

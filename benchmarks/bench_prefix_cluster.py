@@ -13,8 +13,8 @@ family — so prefill collapses to first-toucher + tails.
 
 Both sides pay the same ``prefill_token_cost`` on the virtual clock, and
 host swaps/fetches are charged there too, so the TTFT delta is earned
-reuse, not free transfers. Token streams must stay bit-identical to cold
-contiguous serving (tiers change where pages COME FROM, never what gets
+reuse, not free transfers. Token streams must stay identical to isolated
+generation (tiers change where pages COME FROM, never what gets
 generated).
 
 Rows land in results/prefix_cluster.jsonl (run.py --check validates and
@@ -28,7 +28,6 @@ import numpy as np
 from benchmarks.common import emit, emit_json
 from repro.configs import get_config
 from repro.models import model as M
-from repro.serving.continuous import PipelineBatcher
 from repro.serving.loop import VirtualClock
 from repro.serving.pipeline import AsymmetricPipeline
 from repro.serving.request import Request
@@ -69,7 +68,7 @@ def _workload(cfg):
 
 def _serve(mk_replicas, reqs, **kw):
     router = Router(mk_replicas(), n_slots=2, max_len=MAX_LEN,
-                    cache_layout="paged", block_size=BLOCK,
+                    block_size=BLOCK,
                     stage_blocks=STAGE_BLOCKS, prefix_caching=True,
                     prefill_token_cost=TOKEN_COST, **kw)
     stats = router.serve(reqs, deadline=1e9, clock=VirtualClock())
@@ -88,11 +87,11 @@ def run() -> None:
         return [AsymmetricPipeline(cfg, params, [1, L - 1], [[dev], [dev]])
                 for _ in range(2)]
 
-    # cold contiguous reference: the token-identity oracle
+    # isolated generation: the token-identity oracle
+    ref = AsymmetricPipeline(cfg, params, [1, L - 1], [[dev], [dev]])
     reqs_cold = _workload(cfg)
-    PipelineBatcher(
-        AsymmetricPipeline(cfg, params, [1, L - 1], [[dev], [dev]]),
-        n_slots=2, max_len=MAX_LEN).serve(reqs_cold, deadline=1e9)
+    for r in reqs_cold:
+        r.output = ref.generate(r.prompt[None], max_new=OUT_LEN)[0]
 
     # baseline: private per-replica caches, least-loaded routing,
     # eviction deletes

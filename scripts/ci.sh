@@ -100,7 +100,7 @@ if [[ "$TIER" == "--full" ]]; then
   python -m repro.launch.serve --arch granite-8b --reduced \
     --cluster case_study --rate 4 --duration 1 --deadline 60 \
     --out-len 4 --search-iters 2 --policy continuous \
-    --cache-layout paged --block-size 8 \
+    --block-size 8 \
     --trace-out "$TRACE_TMP/trace.json" \
     --metrics-out "$TRACE_TMP/metrics.jsonl" --calibrate
   python -m repro.obs.report "$TRACE_TMP/metrics.jsonl" \

@@ -9,8 +9,9 @@ Suites:
   kernels  paged decode + context-prefill + multi-token verification
            Pallas kernels in interpret mode (a GPU-less CI's only route
            through the block-table index maps)
-  serving  continuous + paged serving on a 2-stage TP=2 asymmetric pipeline
-           over 4 virtual host devices, paged bit-identical to contiguous
+  serving  paged continuous serving on a 2-stage TP=2 asymmetric pipeline
+           over 4 virtual host devices, token-identical to isolated
+           generation
   prefix   copy-on-write prefix caching + chunked prefill, warm == cold
   disagg   disaggregated prefill/decode with KV-page handoff, token-
            identical to colocated serving on the same 4-device pipeline
@@ -148,23 +149,17 @@ def suite_serving() -> None:
     cfg, asg = _setup()
     reqs = synth_workload(rate=40.0, duration=0.25, vocab=cfg.vocab_size,
                           prompt_len=8, prompt_jitter=5, out_len=4, seed=1)
-    stats = _engine(cfg, asg).serve(reqs, deadline=120.0)
+    # paged continuous serving: per-stage block pools, each request's
+    # tokens identical to isolated generation on the same pipeline
+    eng = _engine(cfg, asg, block_size=8)
+    stats = eng.serve(reqs, deadline=120.0)
     assert len(stats.latencies) == len(reqs) and len(reqs) > 0
     assert stats.attainment == 1.0, stats.summary()
     for r in reqs:
         assert r.output is not None and len(r.output) == 4, r.rid
-    _ok(f"continuous serving: {stats.summary()}")
-
-    # paged serving over the same pipeline: per-stage block pools,
-    # identical outputs to the contiguous pass above
-    reqs_p = synth_workload(rate=40.0, duration=0.25, vocab=cfg.vocab_size,
-                            prompt_len=8, prompt_jitter=5, out_len=4, seed=1)
-    stats_p = _engine(cfg, asg, cache_layout="paged",
-                      block_size=8).serve(reqs_p, deadline=120.0)
-    assert stats_p.attainment == 1.0, stats_p.summary()
-    for r, rp in zip(reqs, reqs_p):
-        assert list(r.output) == list(rp.output), (r.rid,)
-    _ok(f"paged == contiguous: {stats_p.summary()}")
+        want = eng.generate([r.prompt], max_new=4)[0]
+        assert list(r.output) == list(want), (r.rid,)
+    _ok(f"continuous serving == isolated: {stats.summary()}")
 
 
 def suite_prefix() -> None:
@@ -178,10 +173,9 @@ def suite_prefix() -> None:
                                       unique_len=6, out_len=4, seed=3)
 
     reqs_cold = wl()
-    _engine(cfg, asg, cache_layout="paged",
-            block_size=8).serve(reqs_cold, deadline=120.0)
+    _engine(cfg, asg, block_size=8).serve(reqs_cold, deadline=120.0)
     reqs_warm = wl()
-    stats_w = _engine(cfg, asg, cache_layout="paged", block_size=8,
+    stats_w = _engine(cfg, asg, block_size=8,
                       prefix_caching=True,
                       prefill_chunk=16).serve(reqs_warm, deadline=120.0)
     assert stats_w.prefix_hits > 0, stats_w.summary()
@@ -214,10 +208,10 @@ def suite_disagg() -> None:
                               seed=2)
 
     reqs_c = wl()
-    _engine(cfg, asg, cache_layout="paged",
-            block_size=8).serve(reqs_c, deadline=1e9, clock=VirtualClock())
+    _engine(cfg, asg, block_size=8).serve(reqs_c, deadline=1e9,
+                                          clock=VirtualClock())
     reqs_d = wl()
-    stats_d = _engine(cfg, asg, cache_layout="paged", block_size=8,
+    stats_d = _engine(cfg, asg, block_size=8,
                       disaggregate=True).serve(reqs_d, deadline=1e9,
                                                clock=VirtualClock())
     assert stats_d.migrations == len(reqs_d), stats_d.summary()
@@ -250,13 +244,13 @@ def suite_cluster() -> None:
                                       unique_len=6, out_len=4, seed=7)
 
     reqs_c = wl()
-    _engine(cfg, asg, cache_layout="paged",
-            block_size=8).serve(reqs_c, deadline=1e9, clock=VirtualClock())
+    _engine(cfg, asg, block_size=8).serve(reqs_c, deadline=1e9,
+                                          clock=VirtualClock())
     # tiered + clustered: pools too small for the shared set, so hot
     # heads demote to the host tier and come back via promotion or a
     # peer fetch instead of a re-prefill
     reqs_t = wl()
-    stats_t = _engine(cfg, asg, cache_layout="paged", block_size=8,
+    stats_t = _engine(cfg, asg, block_size=8,
                       stage_blocks=[8, 8], prefix_caching=True,
                       host_blocks=32, host_swap_cost=0.01,
                       cluster_prefix=True, prefix_route_weight=0.5,
@@ -284,21 +278,21 @@ def suite_spec() -> None:
                               seed=5)
 
     reqs_b = wl()
-    _engine(cfg, asg, cache_layout="paged",
-            block_size=8).serve(reqs_b, deadline=1e9, clock=VirtualClock())
+    _engine(cfg, asg, block_size=8).serve(reqs_b, deadline=1e9,
+                                          clock=VirtualClock())
     total = sum(len(r.output) for r in reqs_b)
     # n-gram proposing, then self-draft (the acceptance upper bound) —
     # both must reproduce plain greedy decode token for token, in
     # strictly fewer target decode steps for the draft
     reqs_n = wl()
-    st_n = _engine(cfg, asg, cache_layout="paged", block_size=8,
+    st_n = _engine(cfg, asg, block_size=8,
                    spec_decode=True, spec_k=3).serve(
                        reqs_n, deadline=1e9, clock=VirtualClock())
     assert st_n.spec_steps > 0 and st_n.spec_tokens == total
     for rb, rn_ in zip(reqs_b, reqs_n):
         assert list(rb.output) == list(rn_.output), (rb.rid,)
     reqs_d = wl()
-    st_d = _engine(cfg, asg, cache_layout="paged", block_size=8,
+    st_d = _engine(cfg, asg, block_size=8,
                    spec_decode=True, spec_k=3,
                    draft_model=cfg).serve(reqs_d, deadline=1e9,
                                           clock=VirtualClock())
@@ -389,10 +383,9 @@ def suite_quant() -> None:
                               prompt_jitter=5, out_len=4, seed=1)
 
     reqs_f = wl()
-    _engine(cfg, asg, cache_layout="paged",
-            block_size=8).serve(reqs_f, deadline=120.0)
+    _engine(cfg, asg, block_size=8).serve(reqs_f, deadline=120.0)
     reqs_q = wl()
-    stats_q = _engine(cfg, asg, cache_layout="paged", block_size=8,
+    stats_q = _engine(cfg, asg, block_size=8,
                       kv_dtype="int8").serve(reqs_q, deadline=120.0)
     assert stats_q.attainment == 1.0, stats_q.summary()
     assert stats_q.kv_bytes_resident > 0 and stats_q.kv_bytes_saved > 0, \
@@ -422,7 +415,7 @@ def suite_obs() -> None:
                                       unique_len=6, out_len=4, seed=9)
 
     def eng():
-        return _engine(cfg, asg, cache_layout="paged", block_size=8,
+        return _engine(cfg, asg, block_size=8,
                        prefix_caching=True, prefill_chunk=16)
 
     # tracing is pure observation: the traced serve must reproduce the
@@ -484,10 +477,10 @@ def suite_chaos() -> None:
     # survivors regenerate the IDENTICAL token streams (greedy decode),
     # and under --kvsan the kill must release every page (zero leaks)
     reqs_c = wl()
-    _engine(cfg, asg, cache_layout="paged",
-            block_size=8).serve(reqs_c, deadline=1e9, clock=VirtualClock())
+    _engine(cfg, asg, block_size=8).serve(reqs_c, deadline=1e9,
+                                          clock=VirtualClock())
     reqs_k = wl()
-    eng = _engine(cfg, asg, cache_layout="paged", block_size=8)
+    eng = _engine(cfg, asg, block_size=8)
     ctl = OnlineRescheduler(kills=[(2.0, 1)])
     eng.router.attach_controller(ctl)
     stats = eng.serve(reqs_k, deadline=1e9, clock=VirtualClock())
@@ -504,10 +497,10 @@ def suite_chaos() -> None:
     # emitted tokens (pages + sampling state) and the streams continue
     # exactly where they stopped
     reqs_c2 = wl(out_len=6)
-    _engine(cfg, asg, cache_layout="paged",
-            block_size=8).serve(reqs_c2, deadline=1e9, clock=VirtualClock())
+    _engine(cfg, asg, block_size=8).serve(reqs_c2, deadline=1e9,
+                                          clock=VirtualClock())
     reqs_m = wl(out_len=6)
-    eng2 = _engine(cfg, asg, cache_layout="paged", block_size=8)
+    eng2 = _engine(cfg, asg, block_size=8)
     fired = []
 
     def resolver(sig, c, now):

@@ -126,7 +126,7 @@ def serving_config(arch: str, cluster: str, *, kvsan: bool) -> ServingConfig:
         arch=arch, cluster=cluster, rate=1.0, duration=float(N_REQUESTS),
         deadline=600.0, out_len=OUT_LEN, prompt_len=PROMPT_LEN,
         search_iters=4, seed=SEED, policy="continuous",
-        cache_layout="paged", block_size=BLOCK_SIZE, kvsan=kvsan,
+        block_size=BLOCK_SIZE, kvsan=kvsan,
     ).normalized()
 
 

@@ -11,10 +11,9 @@ dataclass that owns:
   * serialization — ``to_args`` round-trips back to an argv list
     (``from_args(parse(to_args(cfg))) == cfg``), ``to_json``/``from_json``
     persist configs into results files and relaunch them;
-  * feature gating — ``normalized()`` applies the layout-compatibility
-    rules (disaggregation/speculation/quantized-KV/host-tier need the
-    paged layout) in ONE place, warning and downgrading exactly like the
-    old inline checks;
+  * feature gating — ``normalized()`` drops the host tier and the
+    cluster prefix directory without prefix caching (``host_tier_gate``,
+    the one statement of that rule, which ``Router`` calls too);
   * derived planning inputs — ``task()``, ``schedule_kwargs()``,
     ``workload()``, ``max_len()``, ``guard_layers()``.
 
@@ -45,6 +44,20 @@ CLUSTERS = {
 }
 
 
+def host_tier_gate(host_tier: bool, cluster_prefix: bool,
+                   prefix_caching: bool) -> bool:
+    """Whether a host page tier or the cluster prefix directory may run:
+    both are keyed by prefix chunk hashes, so both need prefix caching.
+    Warns and returns False when either is asked for without it."""
+    if (host_tier or cluster_prefix) and not prefix_caching:
+        warnings.warn(
+            "the host page tier and the cluster prefix directory need "
+            "prefix caching (they hold prefix blocks keyed by chunk "
+            "hashes); serving without them", stacklevel=3)
+        return False
+    return True
+
+
 def _f(default, help="", choices=None):
     meta: Dict[str, Any] = {"help": help}
     if choices is not None:
@@ -69,23 +82,23 @@ class ServingConfig:
     prompt_len: int = _f(24, "prompt tokens per request")
     search_iters: int = _f(10, "genetic search iterations")
     seed: int = _f(0, "workload / search / params seed")
-    # ---- engine policy and KV layout -----------------------------------
-    policy: str = _f("continuous", "iteration-level slot batching vs the "
-                                   "paper's static whole-batch engine",
+    # ---- engine policy and KV pages ------------------------------------
+    policy: str = _f("continuous", "iteration-level slot batching on paged "
+                                   "KV vs the paper's static whole-batch "
+                                   "engine",
                      choices=("continuous", "static"))
-    cache_layout: str = _f("contiguous", "per-slot max_len cache rows vs "
-                                         "block-paged KV with per-stage "
-                                         "pools (docs/memory.md)",
-                           choices=("contiguous", "paged"))
-    block_size: int = _f(16, "KV page size in tokens (paged layout)")
+    cache_layout: str = _f("paged", "nothing reads it: the continuous "
+                                    "engine is always paged; the field "
+                                    "goes once the benchmark stops "
+                                    "passing it",
+                           choices=("paged",))
+    block_size: int = _f(16, "KV page size in tokens")
     prefix_caching: bool = _f(False, "alias block-aligned shared prompt "
                                      "prefixes copy-on-write and prefill "
-                                     "only cold suffixes (paged layout "
-                                     "only)")
+                                     "only cold suffixes")
     prefill_chunk: int = _f(0, "split prefills longer than this many "
                                "tokens into chunks interleaved with "
-                               "decode iterations (0 = one-shot; paged "
-                               "layout only)")
+                               "decode iterations (0 = one-shot)")
     prefix_hit_rate: float = _f(0.0, "expected fraction of prompt tokens "
                                      "served from the prefix cache; the "
                                      "scheduler plans KV capacity against "
@@ -96,7 +109,7 @@ class ServingConfig:
     # ---- host tier / cluster-wide prefix directory ---------------------
     host_mem_gb: float = _f(0.0, "pool-wide host-memory budget for the "
                                  "page tier (GB), split across replicas "
-                                 "by KV-capacity deficit (paged + "
+                                 "by KV-capacity deficit (needs "
                                  "--prefix-caching)")
     host_swap_gbps: float = _f(0.0, "host<->device swap (and peer-fetch) "
                                     "bandwidth in Gbit/s the scheduler "
@@ -126,8 +139,7 @@ class ServingConfig:
     # ---- disaggregated prefill/decode ----------------------------------
     disaggregate: bool = _f(False, "split prefill and decode across "
                                    "replicas; the scheduler also searches "
-                                   "the role split (paged layout, >= 2 "
-                                   "replicas)")
+                                   "the role split (>= 2 replicas)")
     kv_link_gbps: float = _f(0.0, "flat bandwidth of the prefill->decode "
                                   "KV link in Gbit/s (0 = per-pair costs "
                                   "from the cluster's comm matrices)")
@@ -135,8 +147,8 @@ class ServingConfig:
     spec_decode: bool = _f(False, "speculative decoding: propose up to "
                                   "--spec-k tokens per slot per iteration "
                                   "and commit the verified prefix in one "
-                                  "multi-token target step (paged layout "
-                                  "+ attention-only stacks)")
+                                  "multi-token target step (attention-only "
+                                  "stacks)")
     draft_model: str = _f("", "draft architecture from configs/ for the "
                               "proposer (empty = weight-free n-gram / "
                               "prompt-lookup proposing)")
@@ -162,7 +174,7 @@ class ServingConfig:
                                  "quantized --kv-dtype")
     kvsan: bool = _f(False, "serve under the KVSAN page-lifecycle "
                             "sanitizer; leaks surface as "
-                            "ServeStats.kvsan_leaks (paged layout)")
+                            "ServeStats.kvsan_leaks")
 
     # ---- observability (repro.obs) --------------------------------------
     trace_out: str = _f("", "write a Chrome-trace/Perfetto JSON of the "
@@ -174,6 +186,13 @@ class ServingConfig:
     calibrate: bool = _f(False, "record predicted phase costs alongside "
                                 "observed span durations and print the "
                                 "predicted-vs-observed calibration table")
+
+    def __post_init__(self):
+        if self.cache_layout != "paged":
+            raise ValueError(
+                f"cache_layout={self.cache_layout!r}: the continuous engine "
+                "(PagedPipelineBatcher) serves paged KV only; 'paged' is "
+                "the one value")
 
     # ---- argparse / serialization --------------------------------------
 
@@ -233,40 +252,12 @@ class ServingConfig:
     # ---- feature gating -------------------------------------------------
 
     def normalized(self) -> "ServingConfig":
-        """Apply the layout-compatibility rules, warning on each downgrade
-        (same behavior the launch driver used to inline). Idempotent:
-        a consistent config comes back unchanged."""
+        """Apply the compatibility rules the scheduler must see, warning
+        on each downgrade. Idempotent: a consistent config comes back
+        unchanged."""
         c = dataclasses.replace(self)
-        if c.prefix_hit_rate and c.cache_layout != "paged":
-            warnings.warn(
-                "--prefix-hit-rate only affects capacity planning with "
-                "--cache-layout paged (contiguous replicas are simulated "
-                "unbounded); ignoring it", stacklevel=2)
-            c.prefix_hit_rate = 0.0
-        if c.disaggregate and c.cache_layout != "paged":
-            warnings.warn(
-                "--disaggregate needs --cache-layout paged (the KV "
-                "handoff is a page transfer); serving colocated",
-                stacklevel=2)
-            c.disaggregate = False
-        if c.spec_decode and c.cache_layout != "paged":
-            warnings.warn(
-                "--spec-decode needs --cache-layout paged (multi-token "
-                "verification runs through the paged context path); "
-                "serving without it", stacklevel=2)
-            c.spec_decode = False
-        if c.kv_dtype != "auto" and c.cache_layout != "paged":
-            warnings.warn(
-                "--kv-dtype needs --cache-layout paged (precision is a "
-                "page-pool layout); serving at model precision",
-                stacklevel=2)
-            c.kv_dtype = "auto"
-        if (c.host_mem_gb > 0 or c.cluster_prefix) \
-                and not (c.cache_layout == "paged" and c.prefix_caching):
-            warnings.warn(
-                "--host-mem-gb/--cluster-prefix need --cache-layout "
-                "paged with --prefix-caching (tiers and the directory "
-                "hold prefix blocks); serving without them", stacklevel=2)
+        if not host_tier_gate(c.host_mem_gb > 0, c.cluster_prefix,
+                              c.prefix_caching):
             c.host_mem_gb = 0.0
             c.cluster_prefix = False
         return c
@@ -293,8 +284,7 @@ class ServingConfig:
         return dict(
             deadline=self.deadline, rate=self.rate,
             iters=self.search_iters, seed=self.seed,
-            kv_block_size=(self.block_size
-                           if self.cache_layout == "paged" else None),
+            kv_block_size=self.block_size,
             prefix_hit_rate=self.prefix_hit_rate,
             disaggregate=self.disaggregate,
             kv_link_gbps=self.kv_link_gbps,
@@ -311,11 +301,9 @@ class ServingConfig:
 
     def max_len(self) -> int:
         """Cache capacity per slot: prompt + jitter headroom + decode
-        budget, rounded up to whole pages under the paged layout."""
+        budget, rounded up to whole pages."""
         n = self.prompt_len + self.shared_prefix + 8 + self.out_len
-        if self.cache_layout == "paged":
-            n += (-n) % self.block_size
-        return n
+        return n + (-n) % self.block_size
 
     def guard_layers(self, num_layers: int) -> List[int]:
         """Global layer ids pinned at model precision: the first/last

@@ -1,28 +1,24 @@
 """Continuous (iteration-level) batching — the paper's acknowledged
 limitation (Appendix D), implemented here as a beyond-paper extension.
 
-A replica owns a fixed pool of decode SLOTS backed by pre-allocated caches.
+A replica owns a fixed pool of decode SLOTS over paged KV caches.
 Requests admitted by the serve loop are buffered until the next iteration
 boundary, then prefilled JOINTLY (one right-padded batch with per-row real
-lengths) and their cache rows scattered into free slots; every iteration
-decodes all slots jointly with PER-SLOT positions; finished slots free
-immediately. Right padding keeps each row's token positions identical to
-isolated generation and attention/MoE/SSM state is row-independent, so a
-request's outputs are bit-identical to isolated generation (tested).
+lengths) and their cache rows scattered into the pages of free slots;
+every iteration decodes all slots jointly with PER-SLOT positions;
+finished slots free immediately. Right padding keeps each row's token
+positions identical to isolated generation and attention/MoE/SSM state is
+row-independent, so a request's outputs are bit-identical to isolated
+generation (tested).
 
-Two executors share the slot engine:
+``SlotEngine`` is the slot core (slot state, admission, the joint
+insert/decode iteration); ``PagedPipelineBatcher`` runs it over an
+``AsymmetricPipeline`` replica with per-stage page pools. Works for
+full-KV and recurrent-state architectures; SWA ring caches require
+uniform positions and fall back to static batching (noted).
 
-  * ``ContinuousBatcher``  — the monolithic single-process model apply
-    (one cache pool for the whole stack);
-  * ``PipelineBatcher``    — an ``AsymmetricPipeline`` replica (per-STAGE
-    cache pools, so a multi-stage heterogeneous replica serves at iteration
-    granularity end to end).
-
-Works for full-KV and recurrent-state architectures; SWA ring caches
-require uniform positions and fall back to static batching (noted).
-
-Both implement the replica port of ``serving.loop`` — scheduling, clocking
-and accounting live there, not here.
+The engine implements the replica port of ``serving.loop`` — scheduling,
+clocking and accounting live there, not here.
 """
 from __future__ import annotations
 
@@ -32,11 +28,9 @@ import warnings
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
 from repro.models import model as M
 from repro.obs.trace import NULL_STEP, NULL_TRACER
 from repro.serving.block_manager import (BlockPool, BlockTable, HostPagePool,
@@ -73,8 +67,8 @@ class _Slot:
 
 
 class SlotEngine:
-    """Slot bookkeeping + the joint insert/decode iteration, shared by the
-    monolithic and pipeline executors. Subclasses provide:
+    """The slot core: slot bookkeeping, admission and the joint
+    insert/decode iteration. Subclasses provide:
 
       _prefill_insert(toks (b,P), lens (b,), slot_ids, rids) -> logits (m, V)
           where m = len(slot_ids) <= b; rows beyond m are compile-shape
@@ -355,74 +349,6 @@ class SlotEngine:
         return {req.rid: out for req, out in self._step(0.0)}
 
 
-class ContinuousBatcher(SlotEngine):
-    """Slot-based continuous batching on the monolithic model apply (single
-    jit over the full stack; the asymmetric-pipeline variant is
-    ``PipelineBatcher``)."""
-
-    def __init__(self, cfg: ModelConfig, params, *, n_slots: int = 8,
-                 max_len: int = 256, pad_id: int = 0, key=None,
-                 virtual_step_cost: float = 1.0):
-        from repro.serving.pipeline import slot_mode_supported
-        assert slot_mode_supported(cfg), \
-            "slot mode needs uniform text decode; use static batching"
-        super().__init__(n_slots=n_slots, max_len=max_len,
-                         vocab_size=cfg.vocab_size, pad_id=pad_id,
-                         virtual_step_cost=virtual_step_cost)
-        self.cfg = cfg
-        self.params = params
-        self.cache = M.init_cache(cfg, n_slots, max_len)
-        self._decode = jax.jit(
-            lambda p, t, c, pos: M.decode_step(cfg, p, t, c, pos))
-        self._prefill = jax.jit(
-            lambda p, toks, lens, c: M.prefill(cfg, p, {"tokens": toks}, c,
-                                               lens=lens))
-
-    def _prefill_insert(self, toks, lens, slot_ids, rids=()):
-        m = len(slot_ids)          # toks may carry compile-padding rows > m
-        scratch = M.init_cache(self.cfg, toks.shape[0], self.max_len)
-        logits, scratch = self._prefill(self.params, jnp.asarray(toks),
-                                        jnp.asarray(lens), scratch)
-        # monolithic cache leaves are period-stacked: batch axis is 1
-        rows = jax.tree.map(lambda l: l[:, :m], scratch)
-        self.cache = M.scatter_cache_rows(self.cache, rows, slot_ids,
-                                          batch_axis=1)
-        return np.asarray(logits)[:m]
-
-    def _decode_all(self, toks, pos):
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(toks), self.cache, jnp.asarray(pos))
-        return np.asarray(logits)
-
-
-class PipelineBatcher(SlotEngine):
-    """Slot-based continuous batching over an ``AsymmetricPipeline``
-    replica: per-stage cache pools, iteration-level joint decode with
-    per-slot positions, joint right-padded insert prefill."""
-
-    def __init__(self, pipeline, *, n_slots: int = 8, max_len: int = 256,
-                 pad_id: int = 0, virtual_step_cost: float = 1.0):
-        from repro.serving.pipeline import slot_mode_supported
-        assert slot_mode_supported(pipeline.cfg), \
-            "slot mode needs uniform text decode; use StaticBatcher"
-        super().__init__(n_slots=n_slots, max_len=max_len,
-                         vocab_size=pipeline.cfg.vocab_size, pad_id=pad_id,
-                         virtual_step_cost=virtual_step_cost)
-        self.pipeline = pipeline
-        pipeline.tracer = self.tracer
-
-    def _prefill_insert(self, toks, lens, slot_ids, rids=()):
-        # pools allocate lazily so generate()-only engines never pay for them
-        if (self.pipeline.slot_caches is None
-                or self.pipeline.n_slots != self.n_slots
-                or self.pipeline.slot_len != self.max_len):
-            self.pipeline.init_slot_caches(self.n_slots, self.max_len)
-        return self.pipeline.insert_slots(toks, lens, slot_ids)
-
-    def _decode_all(self, toks, pos):
-        return self.pipeline.decode_slots(toks, pos)
-
-
 class PagedPipelineBatcher(SlotEngine):
     """Slot-based continuous batching over an ``AsymmetricPipeline`` with a
     PAGED KV cache: each stage owns a block pool sized independently
@@ -437,7 +363,7 @@ class PagedPipelineBatcher(SlotEngine):
     max_len / block_size entries); what paging removes is the RESERVATION:
     a slot only ever occupies the blocks its tokens actually fill, so a
     pool sized for actual usage serves far more concurrent slots than
-    max_len-row pre-allocation (benchmarks/bench_paged.py).
+    reserving a max_len row per slot would (benchmarks/bench_paged.py).
 
     ``prefix_caching=True`` cashes in the refcounts: each stage keeps a
     ``PrefixIndex`` (hash of block-aligned prompt chunks -> resident

@@ -58,7 +58,7 @@ class InferenceEngine:
     directly); without it the weight-free n-gram/prompt-lookup proposer
     runs. ``spec_ks`` overrides the depth PER REPLICA (the scheduler's
     acceptance-aware ``SearchResult.spec_ks``; 0 disables speculation on
-    that replica). Needs the paged layout and an attention-only stack.
+    that replica). Needs an attention-only stack.
 
     ``kv_dtype`` stores the paged KV pools at reduced precision
     ("fp32"/"bf16"/"int8"/"fp8"; int8/fp8 pages carry per-token-per-head
@@ -66,11 +66,11 @@ class InferenceEngine:
     overrides PER REPLICA (the scheduler's ``SearchResult.kv_dtypes``;
     None entry = model default); ``kv_guard_layers`` pins those global
     layer indices at model precision (quality guard, typically the
-    first/last layers). Needs the paged layout.
+    first/last layers).
 
     ``kvsan`` serves under the KVSAN page-lifecycle sanitizer
     (repro.analysis.kvsan): pure observation, token-identical, leaks
-    surface as ``ServeStats.kvsan_leaks``. Needs the paged layout.
+    surface as ``ServeStats.kvsan_leaks``.
 
     ``host_blocks`` (one int, or per replica — the scheduler's
     ``SearchResult.host_blocks``) adds a host-memory page tier under each
@@ -88,8 +88,8 @@ class InferenceEngine:
                  params=None, key=None, devices: Optional[Sequence] = None,
                  max_batch: int = 4, quantize: bool = False,
                  policy: str = "continuous", n_slots: int = 8,
-                 max_len: int = 256, cache_layout: str = "contiguous",
-                 block_size: int = 16, stage_blocks=None,
+                 max_len: int = 256, block_size: int = 16,
+                 stage_blocks=None,
                  prefix_caching: bool = False, prefill_chunk: int = 0,
                  host_blocks=0, host_swap_cost: float = 0.0,
                  cluster_prefix: bool = False,
@@ -211,7 +211,6 @@ class InferenceEngine:
                 kv_link = KVLink(gbps=kv_link_gbps)
         self.router = Router(self.replicas, max_batch=max_batch,
                              policy=policy, n_slots=n_slots, max_len=max_len,
-                             cache_layout=cache_layout,
                              block_size=block_size,
                              stage_blocks=stage_blocks,
                              prefix_caching=prefix_caching,
@@ -240,8 +239,8 @@ class InferenceEngine:
                     assignment: Optional[Assignment] = None, key=None,
                     cluster=None, **overrides) -> "InferenceEngine":
         """Build an engine from the two typed surfaces: a
-        ``serving.config.ServingConfig`` (HOW to serve — policy, layout,
-        feature flags) and a ``core.plan.DeploymentPlan`` (WHERE — the
+        ``serving.config.ServingConfig`` (HOW to serve — policy, feature
+        flags) and a ``core.plan.DeploymentPlan`` (WHERE — the
         scheduler's replica layouts, roles, spec depths, KV precisions and
         host-tier split). ``assignment`` overrides the plan's layer split
         (e.g. the reduced-model projection from launch.serve) while the
@@ -255,7 +254,7 @@ class InferenceEngine:
             key=(key if key is not None
                  else jax.random.PRNGKey(sv.seed)),
             policy=sv.policy, max_len=sv.max_len(),
-            cache_layout=sv.cache_layout, block_size=sv.block_size,
+            block_size=sv.block_size,
             prefix_caching=sv.prefix_caching,
             prefill_chunk=sv.prefill_chunk,
             host_blocks=(plan.host_blocks

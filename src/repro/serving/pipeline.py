@@ -394,11 +394,9 @@ class AsymmetricPipeline:
         self.caches = None
         self._pos = 0
         self._kv_start = None
-        # slot-mode state (init_slot_caches): per-stage cache pools
-        self.slot_caches = None
+        # slot-mode state (init_paged_caches): per-stage page pools
         self.n_slots = 0
         self.slot_len = 0
-        # paged slot-mode state (init_paged_caches): per-stage page pools
         self.paged_caches = None
         self.block_size = 0
         self.stage_blocks: List[int] = []
@@ -468,8 +466,8 @@ class AsymmetricPipeline:
         return np.asarray(self._head(x[:, -1:, :])[:, 0])
 
     def _embed_decode_tokens(self, tokens, positions):
-        """Single-token decode embedding (b,1,d), op by op: the contiguous
-        ``decode_step`` and ``decode_slots`` run it. The paged decode call
+        """Single-token decode embedding (b,1,d), op by op: ``decode_step``
+        (isolated generation) runs it. The paged decode call
         gathers inside the first stage's ``decode_paged_call`` program
         instead (``_decode_embed``)."""
         return _decode_embed(self.cfg, self.stages[0].head_params, tokens,
@@ -502,73 +500,9 @@ class AsymmetricPipeline:
         return np.stack(out, axis=1)
 
     # ---- slot mode (continuous batching) -----------------------------------
-    # Each stage owns a pre-allocated cache POOL whose batch rows are decode
-    # slots (allocated lazily on first insert). Arriving requests are prefilled jointly (right-padded, per-row
-    # lengths) through the stage chain on scratch caches and their rows
-    # scattered into free pool slots; decode iterations carry per-slot
-    # positions so slots at different depths share one jitted step.
-
-    def init_slot_caches(self, n_slots: int, max_len: int) -> None:
-        assert slot_mode_supported(self.cfg), \
-            "slot mode needs uniform text decode (SWA ring cache / " \
-            "encoder-decoder / VLM); use static batching"
-        self.n_slots = n_slots
-        self.slot_len = max_len
-        self.slot_caches = [st.make_caches(n_slots, max_len)
-                            for st in self.stages]
-
-    def insert_slots(self, tokens: np.ndarray, lens: np.ndarray,
-                     slot_ids: Sequence[int]) -> np.ndarray:
-        """Joint prefill of right-padded prompts `tokens` (m, P) with real
-        lengths `lens` (m,), scattering each row's caches into pool slot
-        `slot_ids[i]`. Returns each row's last-real-token logits (m, V).
-
-        Right padding keeps every row's token positions identical to
-        isolated generation (bit-identity), and leaves recurrent-state
-        caches holding exactly the post-prompt state; trailing garbage in
-        attention K/V beyond lens[i] is masked by kv_len during decode and
-        progressively overwritten as the slot decodes.
-        """
-        assert self.slot_caches is not None, "call init_slot_caches first"
-        m = len(slot_ids)          # rows beyond m are compile-shape padding
-        b, P = tokens.shape
-        lens = jnp.asarray(lens, jnp.int32)
-        x = self._embed(jnp.asarray(tokens), {})
-        positions = jnp.arange(P)[None].repeat(b, 0)
-        valid = (jnp.arange(P)[None, :] < lens[:, None]).astype(jnp.int32)
-        for si, st in enumerate(self.stages):
-            with st.mesh:
-                x = jax.device_put(x, _rep(st.mesh))
-                scratch = st.make_caches(b, self.slot_len)
-                x, rows = st._prefill_jit(x, scratch, positions, None,
-                                          valid, None, lens)
-                self.slot_caches[si] = [
-                    M.scatter_cache_rows(pool,
-                                         jax.tree.map(lambda r: r[:m], row),
-                                         slot_ids)
-                    for pool, row in zip(self.slot_caches[si], rows)]
-        x_last = x[jnp.arange(m), lens[:m] - 1][:, None]
-        return np.asarray(self._head(x_last)[:, 0])
-
-    def decode_slots(self, tokens: np.ndarray,
-                     positions: np.ndarray) -> np.ndarray:
-        """One decode iteration over ALL slots. tokens (n_slots,) next input
-        token per slot; positions (n_slots,) its absolute position. Free
-        slots decode garbage that is simply discarded. Returns (n_slots, V).
-        """
-        pos = jnp.asarray(positions, jnp.int32)
-        x = self._embed_decode_tokens(jnp.asarray(tokens), pos)
-        for si, st in enumerate(self.stages):
-            with st.mesh:
-                x = jax.device_put(x, _rep(st.mesh))
-                x, self.slot_caches[si] = st._decode_jit(
-                    x, self.slot_caches[si], pos, None, None)
-        return np.asarray(self._head(x)[:, 0])
-
-    # ---- paged slot mode ---------------------------------------------------
-    # Same joint-iteration contract as slot mode, but each stage owns a
-    # BLOCK pool sized independently (∝ its devices' memory — the
-    # asymmetric-capacity point) instead of n_slots pre-cut max_len rows.
+    # Decode slots share one joint iteration with per-slot positions; each
+    # stage owns a BLOCK pool sized independently (∝ its devices' memory —
+    # the asymmetric-capacity point) instead of n_slots pre-cut max_len rows.
     # Block allocation/preemption policy lives in the engine
     # (serving.continuous.PagedPipelineBatcher + serving.block_manager);
     # the pipeline only moves tensors.
@@ -610,12 +544,13 @@ class AsymmetricPipeline:
     def insert_slots_paged(self, tokens: np.ndarray, lens: np.ndarray,
                            slot_ids: Sequence[int],
                            stage_dest: Sequence[np.ndarray]) -> np.ndarray:
-        """Joint right-padded prefill (same compile shapes and math as
-        ``insert_slots``) whose attention rows scatter into stage si's pages
-        at ``stage_dest[si]`` ((m * max_blocks,) physical page per logical
-        block, row-major; null-page entries absorb the padding) and whose
-        recurrent rows scatter by slot id. Returns last-real-token logits
-        (m, V)."""
+        """Joint right-padded prefill of prompts ``tokens`` (m, P) with
+        real lengths ``lens`` on scratch rows of ``slot_len`` (the math of
+        isolated ``prefill``), whose attention rows scatter into stage
+        si's pages at ``stage_dest[si]`` ((m * max_blocks,) physical page
+        per logical block, row-major; null-page entries absorb the
+        padding) and whose recurrent rows scatter by slot id. Returns
+        last-real-token logits (m, V)."""
         assert self.paged_caches is not None, "call init_paged_caches first"
         m = len(slot_ids)          # rows beyond m are compile-shape padding
         b, P = tokens.shape
@@ -836,8 +771,8 @@ class AsymmetricPipeline:
         ends with the final norm and head, so nothing runs op by op around
         them; the tokens, positions and tables go into the call as host
         arrays. ``decode_programs`` counts the programs the call
-        dispatched. The insert, context and verify calls and the
-        contiguous decode still embed and apply the head op by op
+        dispatched. The insert, context and verify calls and isolated
+        ``decode_step`` still embed and apply the head op by op
         (``_embed``, ``_embed_decode_tokens``, ``_head``)."""
         x = np.asarray(tokens, np.int32)
         pos = np.asarray(positions, np.int32)

@@ -17,7 +17,8 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from repro.serving.block_manager import chunk_hashes
-from repro.serving.continuous import PagedPipelineBatcher, PipelineBatcher
+from repro.serving.config import host_tier_gate
+from repro.serving.continuous import PagedPipelineBatcher
 from repro.serving.disagg import KVLink, wire_disaggregation
 from repro.serving.loop import ServeStats, WallClock, run_serve_loop
 from repro.serving.request import Request
@@ -45,7 +46,7 @@ class StaticBatcher:
     max_new_tokens cannot fit is rejected alone with an empty output
     (counted in ``ServeStats.rejected``) instead of crashing the whole
     replay mid-generate — the same graceful degradation SlotEngine._fits
-    gives the continuous engines. None = unbounded (per-generate caches).
+    gives the continuous engine. None = unbounded (per-generate caches).
     """
 
     def __init__(self, replica, *, max_batch: int = 4, pad_id: int = 0,
@@ -113,19 +114,24 @@ class Router:
     """Least-loaded dispatch over replicas, sharing the serve loop (and its
     admission policy) with the SLO simulator.
 
-    ``max_len`` is the serving contract for EVERY policy: slot engines size
-    their caches by it, and the static engine enforces it as its oversized
-    guard — a request too big for the continuous engines is rejected by the
-    static engine too (empty output, counted in ``ServeStats.rejected``)
-    rather than silently served via an unbounded per-generate cache, so
-    static-vs-continuous A/B runs see the same admission ceiling.
-    Construct ``StaticBatcher`` directly with ``max_len=None`` for an
-    unbounded whole-batch engine."""
+    ``policy="continuous"`` builds a ``PagedPipelineBatcher`` per replica;
+    ``policy="static"`` builds a ``StaticBatcher``, which serves without
+    the paged features (speculation, prefix caching, chunking, quantized
+    KV, disaggregation, KVSAN), warning for each one asked for.
+
+    ``max_len`` is the serving contract for EVERY policy: the paged engine
+    sizes its block tables by it, and the static engine enforces it as its
+    oversized guard — a request too big for the continuous engine is
+    rejected by the static engine too (empty output, counted in
+    ``ServeStats.rejected``) rather than silently served via an unbounded
+    per-generate cache, so static-vs-continuous A/B runs see the same
+    admission ceiling. Construct ``StaticBatcher`` directly with
+    ``max_len=None`` for an unbounded whole-batch engine."""
 
     def __init__(self, replicas, *, max_batch: int = 4, pad_id: int = 0,
                  policy: str = "continuous", n_slots: int = 8,
-                 max_len: int = 256, cache_layout: str = "contiguous",
-                 block_size: int = 16, stage_blocks=None,
+                 max_len: int = 256, block_size: int = 16,
+                 stage_blocks=None,
                  prefix_caching: bool = False, prefill_chunk: int = 0,
                  host_blocks=0, host_swap_cost: float = 0.0,
                  cluster_prefix: bool = False,
@@ -143,21 +149,17 @@ class Router:
                  kv_guard_layers: Sequence[int] = (),
                  kvsan: bool = False):
         assert policy in ("continuous", "static"), policy
-        assert cache_layout in ("contiguous", "paged"), cache_layout
         self.replicas = list(replicas)
         self.policy = policy
-        self.cache_layout = cache_layout
         self.block_size = block_size
         # speculative decoding: a SpecConfig shared by every replica, with
         # optional PER-REPLICA depths (the scheduler's acceptance-aware
         # spec_ks — 0 disables speculation on that replica)
-        if spec is not None and (cache_layout != "paged"
-                                 or policy == "static"):
+        if spec is not None and policy == "static":
             warnings.warn(
-                "speculative decoding needs policy='continuous' with "
-                "cache_layout='paged' (multi-token verification runs "
-                "through the paged context path); serving without it",
-                stacklevel=2)
+                "speculative decoding needs policy='continuous' "
+                "(multi-token verification runs through the paged context "
+                "path); serving without it", stacklevel=2)
             spec = None
         if spec_ks is not None:
             assert len(spec_ks) == len(self.replicas), (spec_ks,)
@@ -169,18 +171,15 @@ class Router:
                 return spec
             return dataclasses.replace(spec, k=spec_ks[i]) \
                 if spec_ks[i] >= 1 else None
-        if (prefix_caching or prefill_chunk) and (
-                cache_layout != "paged" or policy == "static"):
+        if (prefix_caching or prefill_chunk) and policy == "static":
             warnings.warn(
                 "prefix_caching / prefill_chunk need policy='continuous' "
-                "with cache_layout='paged' (block-granular aliasing); "
-                "serving without them", stacklevel=2)
+                "(block-granular aliasing); serving without them",
+                stacklevel=2)
             prefix_caching, prefill_chunk = False, 0
-        # host page tier + cluster prefix directory: both are keyed by
-        # prefix chunk hashes, so both need the prefix index. host_blocks
-        # is one capacity for every replica or a per-replica sequence (the
-        # scheduler's SearchResult.host_blocks — big host pools belong
-        # next to small device pools).
+        # host page tier: host_blocks is one capacity for every replica or
+        # a per-replica sequence (the scheduler's SearchResult.host_blocks
+        # — big host pools belong next to small device pools).
         if host_blocks is None:
             host_blocks = 0
         if np.ndim(host_blocks) == 0:
@@ -188,23 +187,20 @@ class Router:
         else:
             host_blocks = [int(b) for b in host_blocks]
             assert len(host_blocks) == len(self.replicas), (host_blocks,)
-        if (any(host_blocks) or cluster_prefix) and not prefix_caching:
-            warnings.warn(
-                "host_blocks / cluster_prefix need prefix_caching=True "
-                "(the page tiers and the directory are keyed by prefix "
-                "chunk hashes); serving without them", stacklevel=2)
+        if not host_tier_gate(any(host_blocks), cluster_prefix,
+                              prefix_caching):
             host_blocks = [0] * len(self.replicas)
             cluster_prefix = False
         self.host_blocks = host_blocks
         # quantized KV pages: ONE pool precision (`kv_dtype`) or the
         # scheduler's PER-REPLICA choices (`kv_dtypes`, None entry = model
         # default). Only the paged continuous engine has page pools.
-        if (kv_dtype is not None or kv_dtypes is not None) and (
-                cache_layout != "paged" or policy == "static"):
+        if (kv_dtype is not None or kv_dtypes is not None) \
+                and policy == "static":
             warnings.warn(
-                "kv_dtype needs policy='continuous' with "
-                "cache_layout='paged' (precision is a page-pool layout); "
-                "serving at model precision", stacklevel=2)
+                "kv_dtype needs policy='continuous' (precision is a "
+                "page-pool layout); serving at model precision",
+                stacklevel=2)
             kv_dtype, kv_dtypes = None, None
         if kv_dtypes is not None:
             assert len(kv_dtypes) == len(self.replicas), (kv_dtypes,)
@@ -217,13 +213,11 @@ class Router:
         # dispatcher wiring prefill workers to decode workers
         if roles is not None and any(r != "both" for r in roles):
             from repro.serving.pipeline import context_mode_supported
-            if (cache_layout != "paged" or policy == "static"
-                    or len(self.replicas) < 2):
+            if policy == "static" or len(self.replicas) < 2:
                 warnings.warn(
-                    "disaggregated roles need policy='continuous' with "
-                    "cache_layout='paged' and >= 2 replicas (the KV "
-                    "handoff is a page transfer); serving colocated",
-                    stacklevel=2)
+                    "disaggregated roles need policy='continuous' and "
+                    ">= 2 replicas (the KV handoff is a page transfer); "
+                    "serving colocated", stacklevel=2)
                 roles = None
             elif self.replicas and not context_mode_supported(
                     self.replicas[0].cfg):
@@ -253,13 +247,13 @@ class Router:
         if step_costs is None:
             step_costs = [1.0] * len(self.replicas)
         assert len(step_costs) == len(self.replicas)
-        if kvsan and (cache_layout != "paged" or policy != "continuous"):
+        if kvsan and policy == "static":
             warnings.warn(
                 "kvsan sanitizes the paged KV lifecycle; "
-                "policy='continuous' with cache_layout='paged' is "
-                "required — serving unsanitized", stacklevel=2)
+                "policy='continuous' is required — serving unsanitized",
+                stacklevel=2)
             kvsan = False
-        if policy == "continuous" and cache_layout == "paged":
+        if policy == "continuous":
             self.workers = [PagedPipelineBatcher(
                 r, n_slots=n_slots, max_len=max_len, pad_id=pad_id,
                 block_size=block_size, stage_blocks=stage_blocks,
@@ -273,19 +267,7 @@ class Router:
                     zip(self.replicas, self.roles, step_costs))]
             self.dispatcher = wire_disaggregation(self.workers, self.roles,
                                                   kv_link)
-        elif policy == "continuous":
-            self.workers = [PipelineBatcher(r, n_slots=n_slots,
-                                            max_len=max_len, pad_id=pad_id,
-                                            virtual_step_cost=sc)
-                            for r, sc in zip(self.replicas, step_costs)]
-            self.dispatcher = None
         else:
-            if cache_layout == "paged":
-                warnings.warn(
-                    "cache_layout='paged' has no effect with "
-                    "policy='static' (the whole-batch engine allocates "
-                    "per-generate caches); serving contiguous",
-                    stacklevel=2)
             self.workers = [StaticBatcher(r, max_batch=max_batch,
                                           pad_id=pad_id, max_len=max_len,
                                           virtual_step_cost=sc)

@@ -8,10 +8,18 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import model as M
-from repro.serving.continuous import ContinuousBatcher
+from repro.serving.continuous import PagedPipelineBatcher
+from repro.serving.pipeline import AsymmetricPipeline
 from repro.serving.request import Request
 
 KEY = jax.random.PRNGKey(0)
+
+
+def _engine(cfg, params, **kw):
+    """The continuous engine over a one-stage pipeline of the whole model."""
+    pipe = AsymmetricPipeline(cfg, params, [cfg.num_layers],
+                              [[jax.devices()[0]]])
+    return PagedPipelineBatcher(pipe, **kw)
 
 
 def _isolated(cfg, params, prompt, n):
@@ -38,7 +46,7 @@ def test_continuous_equals_isolated(arch):
                     prompt=rng.randint(0, cfg.vocab_size,
                                        size=6 + 3 * i).astype(np.int32),
                     max_new_tokens=5) for i in range(3)]
-    cb = ContinuousBatcher(cfg, params, n_slots=2, max_len=48)
+    cb = _engine(cfg, params, n_slots=2, max_len=48, block_size=8)
     cb.serve(reqs, deadline=1e9)
     for r in reqs:
         assert list(r.output) == _isolated(cfg, params, r.prompt, 5), r.rid
@@ -48,13 +56,13 @@ def test_swa_rejected():
     cfg = get_config("h2o-danube-1.8b").reduced()
     params = M.init_params(cfg, KEY)
     with pytest.raises(AssertionError):
-        ContinuousBatcher(cfg, params)
+        _engine(cfg, params)
 
 
 def test_slot_lifecycle():
     cfg = get_config("xlstm-125m").reduced()
     params = M.init_params(cfg, KEY)
-    cb = ContinuousBatcher(cfg, params, n_slots=2, max_len=32)
+    cb = _engine(cfg, params, n_slots=2, max_len=32, block_size=8)
     assert cb.free_slots() == [0, 1]
     r = Request(rid=7, prompt=np.arange(4, dtype=np.int32), max_new_tokens=2)
     cb.insert(r)

@@ -268,18 +268,16 @@ def test_engine_roles_and_gating(setup):
         PipelinePlan([StagePlan([2], L)], 0.1, 0.1),
     ])
     eng = InferenceEngine(cfg, asg, key=KEY, policy="continuous",
-                          n_slots=4, max_len=MAX_LEN, cache_layout="paged",
-                          block_size=BLOCK, disaggregate=True)
+                          n_slots=4, max_len=MAX_LEN, block_size=BLOCK,
+                          disaggregate=True)
     assert eng.roles.count("prefill") == 1
     assert eng.roles.count("decode") == 1
-    # contiguous layout cannot ship pages: falls back to colocated, loudly
+    # the static engine cannot ship pages: falls back to colocated, loudly
     with warnings.catch_warnings(record=True) as ws:
         warnings.simplefilter("always")
-        eng_c = InferenceEngine(cfg, asg, key=KEY, policy="continuous",
-                                n_slots=4, max_len=MAX_LEN,
-                                cache_layout="contiguous",
-                                disaggregate=True)
-    assert eng_c.roles == ["both", "both"]
+        eng_s = InferenceEngine(cfg, asg, key=KEY, policy="static",
+                                max_len=MAX_LEN, disaggregate=True)
+    assert eng_s.roles == ["both", "both"]
     assert any("colocated" in str(w.message) for w in ws)
 
 

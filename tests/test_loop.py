@@ -9,7 +9,7 @@ import pytest
 from repro.configs import get_config
 from repro.core import slo_sim
 from repro.models import model as M
-from repro.serving.continuous import PipelineBatcher
+from repro.serving.continuous import PagedPipelineBatcher
 from repro.serving.loop import VirtualClock, run_serve_loop
 from repro.serving.pipeline import AsymmetricPipeline
 from repro.serving.request import Request, synth_workload
@@ -50,7 +50,7 @@ def test_pipeline_continuous_equals_isolated(arch):
     params = M.init_params(cfg, KEY)
     pipe = _mk_pipeline(cfg, params, n_stages=2)
     reqs = _reqs(cfg, n=4)
-    worker = PipelineBatcher(pipe, n_slots=2, max_len=48)
+    worker = PagedPipelineBatcher(pipe, n_slots=2, max_len=48, block_size=8)
     stats = run_serve_loop([worker], reqs, deadline=1e9,
                            clock=VirtualClock())
     assert len(stats.latencies) == 4
@@ -103,30 +103,6 @@ def test_least_loaded_dispatch_spreads_replicas():
     solo.serve(reqs2, 1e9, clock=VirtualClock())
     # one slot total: the second request queues behind the first
     assert reqs2[0].start_time == 0.0 and reqs2[1].start_time > 0.0
-
-
-def test_oversized_request_rejected_not_fatal():
-    """A request that cannot fit prompt + decode steps in a slot is rejected
-    alone (empty output, warning) instead of crashing the serve loop — even
-    as the FIRST arrival, before any slot cache has been lazily allocated."""
-    cfg = get_config("xlstm-125m").reduced()
-    params = M.init_params(cfg, KEY)
-    pipe = _mk_pipeline(cfg, params, n_stages=2)
-    worker = PipelineBatcher(pipe, n_slots=2, max_len=16)
-    rng = np.random.RandomState(0)
-    lens = [29, 5, 17]                      # oversized, ok, oversized
-    reqs = [Request(rid=i, prompt=rng.randint(0, cfg.vocab_size, size=n
-                                              ).astype(np.int32),
-                    max_new_tokens=3, arrival=0.0)
-            for i, n in enumerate(lens)]
-    with pytest.warns(UserWarning, match="rejected with empty output"):
-        stats = run_serve_loop([worker], reqs, deadline=1e9,
-                               clock=VirtualClock())
-    # rejected requests finish (empty output) but are NOT served: latency
-    # percentiles and throughput cover only the one real completion
-    assert len(stats.latencies) == 1
-    assert stats.rejected == 2 and stats.dropped == 0
-    assert [len(r.output) for r in reqs] == [0, 3, 0]
 
 
 class _StrandingWorker:
@@ -218,7 +194,7 @@ def test_rejected_requests_excluded_from_throughput_and_percentiles():
 
 def test_static_batcher_rejects_oversized_instead_of_crashing():
     """Satellite: StaticBatcher gets the same oversized-request guard the
-    slot engines have — reject alone with an empty output, counted in
+    continuous engine has — reject alone with an empty output, counted in
     ServeStats.rejected, instead of taking down the whole replay."""
     from repro.serving.router import StaticBatcher
     cfg = get_config("xlstm-125m").reduced()

@@ -1,17 +1,16 @@
 """Paged KV cache: block pool/table invariants, paged-vs-contiguous
 gather/scatter round-trips, the Pallas paged-decode kernel, and end-to-end
-bit-identity of paged serving against contiguous serving (including under
-preemption-by-recompute). The correctness bar for the whole refactor is
-BIT-identity: the paged layout must change where cache bytes live, never
-what attention computes."""
-import warnings
-
+token identity of paged serving with isolated generation (including under
+preemption-by-recompute). The correctness bar is identity: the paged
+layout must change where cache bytes live, never what attention
+computes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _hyp import given, settings, st  # noqa: F401 (skips when absent)
+from _isolated import isolated_tokens
 
 from repro.configs import get_config
 from repro.kernels import ops, ref
@@ -20,7 +19,7 @@ from repro.kernels.paged_attention import paged_decode_attention_pallas
 from repro.models import model as M
 from repro.serving.block_manager import (BlockPool, BlockTable, NULL_BLOCK,
                                          blocks_for_tokens)
-from repro.serving.continuous import PagedPipelineBatcher, PipelineBatcher
+from repro.serving.continuous import PagedPipelineBatcher
 from repro.serving.pipeline import AsymmetricPipeline
 from repro.serving.request import Request
 
@@ -221,7 +220,7 @@ def test_decode_step_paged_bit_identical():
 
 
 # ---------------------------------------------------------------------------
-# End-to-end: paged serving == contiguous serving on a 2-stage pipeline
+# End-to-end: paged serving == isolated generation on a 2-stage pipeline
 # ---------------------------------------------------------------------------
 
 def _mk_reqs(cfg, *, n=4, max_new=5, seed=0):
@@ -240,28 +239,25 @@ def _pipe(cfg, params):
 
 
 @pytest.mark.parametrize("arch", ["granite-8b", "jamba-v0.1-52b"])
-def test_pipeline_paged_equals_contiguous(arch):
-    """Tentpole gate: on a 2-stage asymmetric pipeline, paged serving must
-    produce the same tokens as contiguous serving for every request —
-    including hybrid stacks where recurrent layers keep O(1) slot states
-    while attention layers page."""
+def test_pipeline_paged_equals_isolated(arch):
+    """On a 2-stage asymmetric pipeline, paged serving must produce the
+    tokens isolated generation produces for every request — including
+    hybrid stacks where recurrent layers keep O(1) slot states while
+    attention layers page."""
     cfg = get_config(arch).reduced()
     params = M.init_params(cfg, KEY)
-    reqs_c = _mk_reqs(cfg)
-    PipelineBatcher(_pipe(cfg, params), n_slots=3,
-                    max_len=48).serve(reqs_c, deadline=1e9)
     reqs_p = _mk_reqs(cfg)
     stats = PagedPipelineBatcher(_pipe(cfg, params), n_slots=3, max_len=48,
                                  block_size=8).serve(reqs_p, deadline=1e9)
     assert stats.preemptions == 0            # full-occupancy pool
-    for rc, rp in zip(reqs_c, reqs_p):
-        assert list(rc.output) == list(rp.output), rc.rid
+    for want, rp in zip(isolated_tokens(_pipe(cfg, params), reqs_p), reqs_p):
+        assert want == list(rp.output), rp.rid
 
 
 def test_paged_preemption_recomputes_identically():
     """A pool too small for all slots' full generations forces
     preempt-by-recompute; the evicted requests still finish with exactly
-    the tokens contiguous serving produces."""
+    the tokens isolated generation produces."""
     cfg = get_config("granite-8b").reduced()
     params = M.init_params(cfg, KEY)
 
@@ -272,9 +268,6 @@ def test_paged_preemption_recomputes_identically():
                                            size=6).astype(np.int32),
                         max_new_tokens=20, arrival=0.0) for i in range(3)]
 
-    reqs_c = reqs()
-    PipelineBatcher(_pipe(cfg, params), n_slots=3,
-                    max_len=32).serve(reqs_c, deadline=1e9)
     # each request ends at 26 tokens = 4 blocks of 8; three concurrent
     # need 12 blocks but the pools hold 8 usable -> eviction mid-decode
     reqs_p = reqs()
@@ -282,8 +275,8 @@ def test_paged_preemption_recomputes_identically():
         _pipe(cfg, params), n_slots=3, max_len=32, block_size=8,
         stage_blocks=[9, 9], admit_headroom=2).serve(reqs_p, deadline=1e9)
     assert stats.preemptions > 0
-    for rc, rp in zip(reqs_c, reqs_p):
-        assert list(rc.output) == list(rp.output), rc.rid
+    for want, rp in zip(isolated_tokens(_pipe(cfg, params), reqs_p), reqs_p):
+        assert want == list(rp.output), rp.rid
 
 
 @pytest.mark.parametrize("prefill_chunk", [0, 8])
@@ -323,23 +316,35 @@ def test_recomputed_tokens_count_each_thrown_away_token_once(prefill_chunk):
     assert [list(o) for o in out_small] == [list(o) for o in out_full]
 
 
-def test_oversized_request_rejected_and_counted():
-    cfg = get_config("granite-8b").reduced()
+@pytest.mark.parametrize("arch,max_len,asks", [
+    # the FIRST arrival is oversized, before any device cache exists
+    ("xlstm-125m", 16, [(29, 3), (5, 3), (17, 3)]),
+    ("granite-8b", 32, [(5, 5), (8, 5), (40, 20)]),
+], ids=["recurrent-first-oversized", "attention"])
+def test_oversized_request_rejected_and_counted(arch, max_len, asks):
+    """A request that cannot fit prompt + decode steps in a slot is
+    rejected alone (empty output, warning, counted in
+    ``ServeStats.rejected``) instead of crashing the serve loop."""
+    cfg = get_config(arch).reduced()
     params = M.init_params(cfg, KEY)
-    reqs = _mk_reqs(cfg, n=2) + [
-        Request(rid=99, prompt=np.arange(40, dtype=np.int32),
-                max_new_tokens=20, arrival=0.0)]
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
+    rng = np.random.RandomState(0)
+    reqs = [Request(rid=i, prompt=rng.randint(0, cfg.vocab_size, size=n
+                                              ).astype(np.int32),
+                    max_new_tokens=k, arrival=0.0)
+            for i, (n, k) in enumerate(asks)]
+    fits = [n + k <= max_len - 1 for n, k in asks]
+    with pytest.warns(UserWarning, match="rejected with empty output"):
         stats = PagedPipelineBatcher(
-            _pipe(cfg, params), n_slots=2, max_len=32,
+            _pipe(cfg, params), n_slots=2, max_len=max_len,
             block_size=8).serve(reqs, deadline=1e9)
-    assert stats.rejected == 1
-    assert len(reqs[-1].output) == 0
-    for r in reqs[:2]:
-        assert len(r.output) == r.max_new_tokens
-    # a rejected request served nobody: it cannot count as SLO-attained
-    assert stats.attainment == pytest.approx(2 / 3)
+    assert stats.rejected == fits.count(False) and stats.dropped == 0
+    assert [len(r.output) for r in reqs] == [
+        k if f else 0 for (_, k), f in zip(asks, fits)]
+    # rejected requests finish (empty output) but are NOT served: latency
+    # percentiles and throughput cover only the real completions, and a
+    # rejected request cannot count as SLO-attained
+    assert len(stats.latencies) == fits.count(True)
+    assert stats.attainment == pytest.approx(fits.count(True) / len(asks))
 
 
 def test_search_kv_capacity_bound():

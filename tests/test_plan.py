@@ -5,6 +5,7 @@ the deprecated SearchResult property shim, and the ServingConfig
 argv/json round-trips."""
 import argparse
 import dataclasses
+import json
 import warnings
 
 import numpy as np
@@ -179,7 +180,7 @@ def test_search_result_deprecated_properties(name, value):
 
 def test_serving_config_argv_round_trip():
     cfg = ServingConfig(arch="granite-8b", reduced=True, rate=7.5,
-                        cache_layout="paged", prefix_caching=True,
+                        policy="static", prefix_caching=True,
                         kvsan=True, kv_dtype="search", spec_decode=True,
                         spec_k=3, route_seed=11, host_mem_gb=2.0,
                         shared_prefix=16, disaggregate=True)
@@ -189,7 +190,7 @@ def test_serving_config_argv_round_trip():
 
 def test_serving_config_json_round_trip():
     cfg = ServingConfig(arch="llama2-70b", block_size=32, kv_dtype="fp8",
-                        cache_layout="paged", prefill_chunk=64)
+                        prefill_chunk=64)
     assert ServingConfig.from_json(cfg.to_json()) == cfg
 
 
@@ -207,10 +208,14 @@ def test_normalized_gates_paged_features():
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         ok = bad.normalized()
-    assert len(w) == 5
-    assert not ok.disaggregate and not ok.spec_decode
-    assert ok.kv_dtype == "auto" and ok.host_mem_gb == 0.0
-    assert not ok.cluster_prefix and ok.prefix_hit_rate == 0.0
+    # the one gate left: the host tier and the cluster directory need
+    # prefix caching; every paged feature else passes through
+    assert len(w) == 1 and "prefix caching" in str(w[0].message)
+    assert ok.host_mem_gb == 0.0 and not ok.cluster_prefix
+    assert ok.disaggregate and ok.spec_decode
+    assert ok.kv_dtype == "fp8" and ok.prefix_hit_rate == 0.5
+    with_prefix = dataclasses.replace(bad, prefix_caching=True)
+    assert with_prefix.normalized() == with_prefix
     # idempotent: a consistent config passes through silently
     with warnings.catch_warnings():
         warnings.simplefilter("error")
@@ -218,11 +223,32 @@ def test_normalized_gates_paged_features():
 
 
 def test_max_len_rounds_to_blocks():
-    cfg = ServingConfig(prompt_len=10, out_len=5, cache_layout="paged",
-                        block_size=16)
-    assert cfg.max_len() % 16 == 0
-    cont = ServingConfig(prompt_len=10, out_len=5)
-    assert cont.max_len() == 10 + 8 + 5
+    cfg = ServingConfig(prompt_len=10, out_len=5, block_size=16)
+    assert cfg.max_len() == 32                # 10 + 8 + 5, whole pages
+    assert ServingConfig(prompt_len=10, out_len=5,
+                         block_size=8).max_len() == 24
+    assert ServingConfig(prompt_len=11, out_len=5,
+                         block_size=8).max_len() == 24
+
+
+def test_paged_is_the_only_cache_layout():
+    """The continuous engine is always paged: ``ServingConfig`` takes
+    ``cache_layout="paged"`` only, from the constructor and from JSON,
+    and ``Router``/``InferenceEngine`` take no layout at all."""
+    import inspect
+    from repro.serving.engine import InferenceEngine
+    from repro.serving.router import Router
+    assert ServingConfig().cache_layout == "paged"
+    assert ServingConfig(cache_layout="paged") == ServingConfig()
+    for gone in ("contiguous", ""):
+        with pytest.raises(ValueError, match="PagedPipelineBatcher"):
+            ServingConfig(cache_layout=gone)
+        doc = json.loads(ServingConfig().to_json())
+        doc["cache_layout"] = gone
+        with pytest.raises(ValueError, match="PagedPipelineBatcher"):
+            ServingConfig.from_json(json.dumps(doc))
+    for cls in (Router, InferenceEngine):
+        assert "cache_layout" not in inspect.signature(cls).parameters
 
 
 def test_guard_layers_pins_both_ends():

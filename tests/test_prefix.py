@@ -2,8 +2,8 @@
 
 Correctness bar (same as the paged refactor): warm-prefix serving and
 chunked prefill must change WHERE prefill compute and cache bytes come
-from, never what gets generated — outputs are token-identical to cold
-one-shot serving on a multi-stage asymmetric pipeline. Host-side refcount
+from, never what gets generated — outputs are token-identical to isolated
+generation on a multi-stage asymmetric pipeline. Host-side refcount
 bookkeeping (PrefixIndex / BlockTable.writable) is checked against an
 independent reference-count model under randomized match/alias/COW/release
 interleavings: no block leaked, none double-freed.
@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from _hyp import given, settings, st  # noqa: F401 (skips when absent)
+from _isolated import serve_isolated
 
 from repro.configs import get_config
 from repro.kernels import ops, ref
@@ -24,7 +25,7 @@ from repro.models import model as M
 from repro.serving.block_manager import (BlockPool, BlockTable, NULL_BLOCK,
                                          PrefixIndex, blocks_for_tokens,
                                          chunk_hashes)
-from repro.serving.continuous import PagedPipelineBatcher, PipelineBatcher
+from repro.serving.continuous import PagedPipelineBatcher
 from repro.serving.pipeline import AsymmetricPipeline, context_mode_supported
 from repro.serving.request import Request, shared_prefix_workload
 
@@ -330,7 +331,7 @@ def test_copy_cache_pages_duplicates_attn_leaves_only():
 
 
 # ---------------------------------------------------------------------------
-# End-to-end: warm-prefix / chunked serving == cold serving (2-stage pipe)
+# End-to-end: warm-prefix / chunked serving == isolated generation
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -368,9 +369,7 @@ def served_cold():
                             arrival=25.0))
         return reqs
 
-    reqs_c = mk_reqs()
-    PipelineBatcher(pipe(), n_slots=3, max_len=48).serve(reqs_c,
-                                                         deadline=1e9)
+    reqs_c = serve_isolated(pipe(), mk_reqs())
     return cfg, params, pipe, mk_reqs, reqs_c
 
 
@@ -494,7 +493,7 @@ def test_chunk_calls_pad_rows_to_a_power_of_two():
 def test_prefix_cache_eviction_under_pool_pressure():
     """A pool too small to keep every cached prefix resident must evict
     LRU prefixes (not crash, not corrupt): distinct prompts streamed
-    through a tight pool still decode exactly like cold serving."""
+    through a tight pool still decode exactly like isolated generation."""
     cfg = get_config("granite-8b").reduced()
     params = M.init_params(cfg, KEY)
     dev = jax.devices()[0]
@@ -510,9 +509,7 @@ def test_prefix_cache_eviction_under_pool_pressure():
                         max_new_tokens=4, arrival=1.0 * i)
                 for i in range(5)]
 
-    reqs_c = mk()
-    PipelineBatcher(pipe(), n_slots=2, max_len=32).serve(reqs_c,
-                                                         deadline=1e9)
+    reqs_c = serve_isolated(pipe(), mk())
     reqs_p = mk()
     # 9 usable blocks: one request needs 3; five distinct cached prefixes
     # (2 full blocks each) cannot all stay resident

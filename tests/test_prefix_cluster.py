@@ -3,15 +3,17 @@ prefix-aware routing.
 
 Correctness bar (same as every serving feature): tiers and the directory
 change WHERE pages come from — device pool, host spill, a peer replica —
-never what gets generated. Outputs stay token-identical to cold serving;
-tier bookkeeping is checked against an independent model under randomized
-demote/promote/fetch interleavings (every page in exactly one tier,
-refcounts conserved, the directory never pointing at a freed page)."""
+never what gets generated. Outputs stay token-identical to isolated
+generation; tier bookkeeping is checked against an independent model under
+randomized demote/promote/fetch interleavings (every page in exactly one
+tier, refcounts conserved, the directory never pointing at a freed
+page)."""
 import jax
 import numpy as np
 import pytest
 
 from _hyp import given, settings, st  # noqa: F401 (skips when absent)
+from _isolated import serve_isolated
 
 from repro.configs import get_config
 from repro.core import cluster as cl
@@ -23,7 +25,7 @@ from repro.serving.block_manager import (BlockPool, BlockTable,
                                          chunk_hashes)
 from repro.serving.cluster_kv import (ClusterPrefixDirectory,
                                       wire_cluster_prefix)
-from repro.serving.continuous import PagedPipelineBatcher, PipelineBatcher
+from repro.serving.continuous import PagedPipelineBatcher
 from repro.serving.loop import VirtualClock, run_serve_loop
 from repro.serving.pipeline import AsymmetricPipeline
 from repro.serving.request import Request
@@ -290,8 +292,8 @@ def two_replica_router_parts():
     def mk_router(**kw):
         reps = [AsymmetricPipeline(cfg, params, [L], [[dev]])
                 for _ in range(2)]
-        base = dict(n_slots=2, max_len=48, cache_layout="paged",
-                    block_size=8, prefix_caching=True)
+        base = dict(n_slots=2, max_len=48, block_size=8,
+                    prefix_caching=True)
         base.update(kw)
         return Router(reps, **base)
 
@@ -367,9 +369,7 @@ def cluster_served_cold():
                                 arrival=0.3 * i))
         return reqs
 
-    reqs_c = mk_reqs()
-    PipelineBatcher(pipe(), n_slots=2, max_len=48).serve(reqs_c,
-                                                         deadline=1e9)
+    reqs_c = serve_isolated(pipe(), mk_reqs())
     return cfg, params, pipe, mk_reqs, reqs_c
 
 

@@ -331,7 +331,7 @@ def chaos_setup():
     def engine():
         return InferenceEngine(cfg, asg, key=jax.random.PRNGKey(0),
                                policy="continuous", n_slots=4, max_len=48,
-                               cache_layout="paged", block_size=BLOCK,
+                               block_size=BLOCK,
                                kvsan=True)
 
     cold = wl()

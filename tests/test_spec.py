@@ -495,7 +495,7 @@ def test_spec_counters_and_bounds(setup):
     assert stats.spec_tokens <= stats.spec_steps * (k + 1)
 
 
-def test_spec_gating_warns_on_hybrid_and_contiguous():
+def test_spec_gating_warns_on_hybrid_and_static():
     cfg_h = get_config("jamba-v0.1-52b").reduced()
     params_h = M.init_params(cfg_h, KEY)
     dev = jax.devices()[0]
@@ -507,15 +507,14 @@ def test_spec_gating_warns_on_hybrid_and_contiguous():
                                    spec=SpecConfig(k=2))
     assert eng.spec is None
     assert any("attention-only" in str(x.message) for x in w)
-    # router-level gating: contiguous layout cannot verify through pages
+    # router-level gating: the static engine cannot verify through pages
     from repro.serving.router import Router
     cfg = get_config("granite-8b").reduced()
     params = M.init_params(cfg, KEY)
     pipe = AsymmetricPipeline(cfg, params, [cfg.num_layers], [[dev]])
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
-        r = Router([pipe], cache_layout="contiguous",
-                   spec=SpecConfig(k=2))
+        Router([pipe], policy="static", spec=SpecConfig(k=2))
     assert any("paged" in str(x.message) for x in w)
 
 
@@ -533,7 +532,7 @@ def test_engine_unsuitable_draft_falls_back_to_ngram():
         warnings.simplefilter("always")
         eng = InferenceEngine(
             cfg, asg, key=KEY, policy="continuous", n_slots=2, max_len=32,
-            cache_layout="paged", block_size=8, spec_decode=True, spec_k=2,
+            block_size=8, spec_decode=True, spec_k=2,
             draft_model="h2o-danube-1.8b")     # SWA stack: no rollback
     assert any("n-gram" in str(x.message) for x in w)
     worker = eng.router.workers[0]
